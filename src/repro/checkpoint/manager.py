@@ -42,6 +42,8 @@ import time
 from dataclasses import dataclass, field
 from typing import Callable
 
+import jax
+
 from repro.checkpoint import serialization as ser
 from repro.core import Sea
 
@@ -229,7 +231,7 @@ class CheckpointManager:
             else:
                 for fname, arr, entry in jobs:
                     self._write_leaf(d, fname, arr, entry, None)
-            if ser.process_index() == 0:
+            if jax.process_index() == 0:
                 ser.write_manifest(manifest, d, open_fn=self._open)
                 with self._open(os.path.join(d, _MARKER), "w") as f:
                     f.write(json.dumps({"step": handle.step}))
@@ -310,7 +312,7 @@ class CheckpointManager:
         leaked both ways: un-markered step dirs are invisible to
         ``available_steps`` so they were never cleaned, and pruned steps
         left their empty ``step_XXXXXXXX`` directory behind."""
-        if ser.process_index() != 0:
+        if jax.process_index() != 0:
             return
         fs = self.sea.fs
         try:

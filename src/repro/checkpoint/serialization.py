@@ -28,15 +28,6 @@ import jax.numpy as jnp
 import numpy as np
 
 
-def process_index() -> int:
-    """This host's rank (0 on single-process runs): the rank that owns
-    manifest + marker writes."""
-    try:
-        return jax.process_index()
-    except Exception:
-        return 0
-
-
 def _path_str(path) -> str:
     parts = []
     for p in path:

@@ -86,23 +86,11 @@ def pipeline_forward(
 
     in_specs = (P(axis), P())        # params sharded by stage; x replicated
     out_specs = P()
-    fn = _shard_map(per_stage, mesh, in_specs, out_specs)
-    return fn(params_stacked, x_microbatches)
-
-
-def _shard_map(f, mesh, in_specs, out_specs):
-    # jax >= 0.5 exposes jax.shard_map (replication check kwarg: check_vma);
-    # older releases only have jax.experimental.shard_map (check_rep).
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(
-            f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-            check_vma=False,
-        )
-    from jax.experimental.shard_map import shard_map
-
-    return shard_map(
-        f, mesh=mesh, in_specs=in_specs, out_specs=out_specs, check_rep=False
+    fn = jax.shard_map(
+        per_stage, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
+        check_vma=False,
     )
+    return fn(params_stacked, x_microbatches)
 
 
 def split_microbatches(x: jax.Array, n_micro: int) -> jax.Array:

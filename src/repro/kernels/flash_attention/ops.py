@@ -1,8 +1,8 @@
 """jit'd public wrapper for the flash-attention kernel.
 
 Model code keeps [B, S, H, Dh] layout; the kernel wants [B, H, S, Dh].
-``interpret`` defaults to True off-TPU so the same call sites validate on
-CPU and run the Mosaic kernel on TPU.
+``interpret=True`` runs the Pallas interpreter (CPU validation); the
+default compiles the Mosaic kernel for the TPU.
 """
 
 from __future__ import annotations
@@ -12,10 +12,6 @@ from functools import partial
 import jax
 
 from .kernel import flash_attention_kernel
-
-
-def _on_tpu() -> bool:
-    return jax.default_backend() == "tpu"
 
 
 @partial(
@@ -31,10 +27,8 @@ def flash_attention(
     window: int | None = None,
     block_q: int = 512,
     block_k: int = 512,
-    interpret: bool | None = None,
+    interpret: bool = False,
 ) -> jax.Array:
-    if interpret is None:
-        interpret = not _on_tpu()
     out = flash_attention_kernel(
         q.transpose(0, 2, 1, 3),
         k.transpose(0, 2, 1, 3),

@@ -11,9 +11,7 @@ from .kernel import rmsnorm_kernel
 
 @partial(jax.jit, static_argnames=("eps", "block_rows", "interpret"))
 def rmsnorm(x, scale, *, eps: float = 1e-5, block_rows: int = 256,
-            interpret: bool | None = None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+            interpret: bool = False):
     return rmsnorm_kernel(
         x, scale, eps=eps, block_rows=block_rows, interpret=interpret
     )

@@ -6,10 +6,10 @@ blocks are parallel. Within a chunk the recurrence
 
     h_t = e^{Δ_t A} h_{t-1} + (Δ_t x_t) B_t ;   y_t = h_t · C_t + D x_t
 
-runs as a ``fori_loop`` over L steps of [dblk, N] vector work (VPU); the
+runs as a ``fori_loop`` over L steps of [N, dblk] vector work (VPU); the
 O(T) dependency chain costs only T/L sequential *grid* steps of HBM
-traffic. The [L, dblk, N] decay tensor stays in VMEM (4 MiB at the
-default L=64, dblk=256, N=16).
+traffic. Each step indexes its own row of the input blocks, so no
+[L, dblk, N] decay tensor is materialized.
 """
 
 from __future__ import annotations
@@ -21,36 +21,31 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
 
-def _ssm_kernel(dt_ref, x_ref, b_ref, c_ref, a_ref, d_ref, y_ref, h_ref,
-                *, L: int, dblk: int, N: int):
+def _ssm_kernel(dt_ref, x_ref, b_ref, c_ref, at_ref, d_ref, y_ref, h_ref,
+                *, L: int):
     ci = pl.program_id(2)
 
     @pl.when(ci == 0)
     def _init():
         h_ref[...] = jnp.zeros_like(h_ref)
 
-    dt = dt_ref[0].astype(jnp.float32)            # [L, dblk]
-    x = x_ref[0].astype(jnp.float32)              # [L, dblk]
-    Bm = b_ref[0].astype(jnp.float32)             # [L, N]
-    Cm = c_ref[0].astype(jnp.float32)             # [L, N]
-    A = a_ref[...].astype(jnp.float32)            # [dblk, N]
+    At = at_ref[...].astype(jnp.float32)          # [N, dblk]
     D = d_ref[...].astype(jnp.float32)            # [1, dblk]
 
-    da = jnp.exp(dt[:, :, None] * A[None])        # [L, dblk, N]
-    dbx = (dt * x)[:, :, None] * Bm[:, None, :]   # [L, dblk, N]
+    # time is a leading (untiled) block dim, so step t reads and writes
+    # its rows by ref index; the state is kept transposed, [N, dblk], so
+    # the contraction over N is a sublane reduction
+    def step(t, h):
+        dt = dt_ref[0, t].astype(jnp.float32)     # [1, dblk]
+        x = x_ref[0, t].astype(jnp.float32)       # [1, dblk]
+        b = b_ref[0, t].astype(jnp.float32)       # [N, 1]
+        c = c_ref[0, t].astype(jnp.float32)       # [N, 1]
+        h = jnp.exp(dt * At) * h + b * (dt * x)   # [N, dblk]
+        y = jnp.sum(h * c, axis=0, keepdims=True) + D * x
+        y_ref[0, t] = y.astype(y_ref.dtype)
+        return h
 
-    def step(t, carry):
-        h, y = carry
-        h = da[t] * h + dbx[t]                    # [dblk, N]
-        yt = jnp.sum(h * Cm[t][None, :], axis=-1)  # [dblk]
-        y = jax.lax.dynamic_update_index_in_dim(y, yt, t, axis=0)
-        return h, y
-
-    h0 = h_ref[...]
-    y0 = jnp.zeros((L, dblk), jnp.float32)
-    h_fin, y = jax.lax.fori_loop(0, L, step, (h0, y0))
-    h_ref[...] = h_fin
-    y_ref[0] = (y + x * D).astype(y_ref.dtype)
+    h_ref[...] = jax.lax.fori_loop(0, L, step, h_ref[...])
 
 
 def ssm_scan_kernel(
@@ -72,12 +67,12 @@ def ssm_scan_kernel(
     assert T % L == 0 and d_in % dblk == 0
     nc, nd = T // L, d_in // dblk
     grid = (B, nd, nc)
-    kern = functools.partial(_ssm_kernel, L=L, dblk=dblk, N=N)
-    chan_spec = pl.BlockSpec((1, L, dblk), lambda b, d, c: (b, c, d))
-    state_spec = pl.BlockSpec((1, L, N), lambda b, d, c: (b, c, 0))
+    kern = functools.partial(_ssm_kernel, L=L)
+    chan_spec = pl.BlockSpec((1, L, 1, dblk), lambda b, d, c: (b, c, 0, d))
+    state_spec = pl.BlockSpec((1, L, N, 1), lambda b, d, c: (b, c, 0, 0))
     from jax.experimental.pallas import tpu as pltpu
 
-    return pl.pallas_call(
+    y = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -85,11 +80,19 @@ def ssm_scan_kernel(
             chan_spec,
             state_spec,
             state_spec,
-            pl.BlockSpec((dblk, N), lambda b, d, c: (d, 0)),
+            pl.BlockSpec((N, dblk), lambda b, d, c: (0, d)),
             pl.BlockSpec((1, dblk), lambda b, d, c: (0, d)),
         ],
         out_specs=chan_spec,
-        out_shape=jax.ShapeDtypeStruct((B, T, d_in), jnp.float32),
-        scratch_shapes=[pltpu.VMEM((dblk, N), jnp.float32)],
+        out_shape=jax.ShapeDtypeStruct((B, T, 1, d_in), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((N, dblk), jnp.float32)],
         interpret=interpret,
-    )(dt, x, Bm, Cm, A, D.reshape(1, d_in))
+    )(
+        dt.reshape(B, T, 1, d_in),
+        x.reshape(B, T, 1, d_in),
+        Bm.reshape(B, T, N, 1),
+        Cm.reshape(B, T, N, 1),
+        A.T,
+        D.reshape(1, d_in),
+    )
+    return y.reshape(B, T, d_in)

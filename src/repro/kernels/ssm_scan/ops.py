@@ -11,9 +11,7 @@ from .kernel import ssm_scan_kernel
 
 @partial(jax.jit, static_argnames=("chunk", "dblk", "interpret"))
 def ssm_scan(dt, x, Bm, Cm, A, D, *, chunk: int = 64, dblk: int = 256,
-             interpret: bool | None = None):
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
+             interpret: bool = False):
     return ssm_scan_kernel(
         dt, x, Bm, Cm, A, D, chunk=chunk, dblk=dblk, interpret=interpret
     )

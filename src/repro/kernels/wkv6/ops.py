@@ -10,10 +10,8 @@ from .kernel import wkv6_kernel
 
 
 @partial(jax.jit, static_argnames=("chunk", "interpret"))
-def wkv6(r, k, v, w_log, u, *, chunk: int = 64, interpret: bool | None = None):
+def wkv6(r, k, v, w_log, u, *, chunk: int = 64, interpret: bool = False):
     """r,k,v,w_log: [B, T, H, N]; u: [H, N] -> [B, T, H, N] fp32."""
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     out = wkv6_kernel(
         r.transpose(0, 2, 1, 3),
         k.transpose(0, 2, 1, 3),

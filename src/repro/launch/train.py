@@ -5,6 +5,9 @@ burst-buffer checkpoints -> crash-safe resume.
         --arch granite-3-2b --reduce --steps 200 --batch 8 --seq 256 \
         --workdir /tmp/sea_run --ckpt-every 25
 
+``--n-layers`` keeps an architecture's published widths and cuts only its
+depth (``chip_smoke.py`` runs granite-3-2b at 8 of 40 layers on one chip).
+
 The same driver powers the fault-tolerance integration test
 (--simulate-failure N aborts the process mid-run; a relaunch with the
 same workdir resumes from the latest complete checkpoint).
@@ -27,6 +30,7 @@ from repro.configs.base import get_config
 from repro.core import Sea
 from repro.data.pipeline import DataPipeline, write_dataset
 from repro.distributed.fault import HeartbeatMonitor
+from repro.launch.compile_cache import setup_compile_cache
 from repro.training.optimizer import AdamWConfig, OptimizerConfig, Schedule
 from repro.training.train_step import TrainConfig, make_train_step
 
@@ -57,23 +61,33 @@ def small_lm(n_params_m: int = 20, vocab: int = 8192):
     )
 
 
+# placement reserves max_file_size per open write; an .npy file is its
+# payload plus a header that is well under this
+_NPY_HEADER_ROOM = 1 << 12
+
+
 def build_model_config(args):
     if args.arch == "small":
-        return small_lm(args.params_m)
-    cfg = get_config(args.arch)
-    if args.reduce:
-        from repro.configs.archs import reduced
+        cfg = small_lm(args.params_m)
+    else:
+        cfg = get_config(args.arch)
+        if args.reduce:
+            from repro.configs.archs import reduced
 
-        cfg = reduced(cfg)
+            cfg = reduced(cfg)
+    if args.n_layers:
+        cfg = dataclasses.replace(cfg, n_layers=args.n_layers)
     return cfg
 
 
-def main(argv=None) -> dict:
+def parse_args(argv=None) -> argparse.Namespace:
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="small",
                     help="'small' or any assigned arch id (with --reduce)")
     ap.add_argument("--params-m", type=int, default=20)
     ap.add_argument("--reduce", action="store_true")
+    ap.add_argument("--n-layers", type=int, default=0,
+                    help="cut the depth to this many layers, widths kept")
     ap.add_argument("--steps", type=int, default=100)
     ap.add_argument("--batch", type=int, default=8)
     ap.add_argument("--seq", type=int, default=256)
@@ -86,28 +100,11 @@ def main(argv=None) -> dict:
                     help="abort() at this step (fault-tolerance testing)")
     ap.add_argument("--n-shards", type=int, default=8)
     ap.add_argument("--quiet", action="store_true")
-    args = ap.parse_args(argv)
+    return ap.parse_args(argv)
 
-    cfg = build_model_config(args)
-    os.makedirs(args.workdir, exist_ok=True)
-    sea = Sea(checkpoint_sea_config(
-        args.workdir, max_file_size=1 << 24, n_procs=2
-    )).start()
-    log = (lambda *a: None) if args.quiet else (lambda *a: print(*a, flush=True))
 
-    # ---- dataset (build once; later runs reuse the persistent copy) --------
-    ds_meta = os.path.join(sea.fs.mount, "dataset", "corpus", "meta.json")
-    if not sea.fs.exists(ds_meta):
-        log(f"[data] writing {args.n_shards} shards through Sea")
-        write_dataset(
-            sea, "corpus",
-            n_shards=args.n_shards,
-            tokens_per_shard=args.batch * (args.seq + 1) * 16,
-            vocab_size=cfg.vocab_size,
-        )
-
-    # ---- train step ----------------------------------------------------------
-    tcfg = TrainConfig(
+def train_config(args, cfg) -> TrainConfig:
+    return TrainConfig(
         optimizer=OptimizerConfig(
             kind="adamw",
             adamw=AdamWConfig(
@@ -120,18 +117,50 @@ def main(argv=None) -> dict:
         compression=args.compression,
         seq_chunk_loss=min(args.seq, 512),
     )
-    init_state, train_step, _ = make_train_step(cfg, tcfg)
+
+
+def main(argv=None) -> dict:
+    args = parse_args(argv)
+    setup_compile_cache()
+    cfg = build_model_config(args)
+    log = (lambda *a: None) if args.quiet else (lambda *a: print(*a, flush=True))
+
+    # ---- train step ----------------------------------------------------------
+    init_state, train_step, _ = make_train_step(cfg, train_config(args, cfg))
     train_step = jax.jit(train_step, donate_argnums=0)
+    template = jax.eval_shape(init_state, jax.ShapeDtypeStruct((2,), jnp.uint32))
+
+    # ---- Sea: placement admits a write where n_procs * max_file_size is
+    # free, so size it for the largest file this job writes --------------
+    tokens_per_shard = args.batch * (args.seq + 1) * 16
+    largest = max(
+        max(x.size * x.dtype.itemsize for x in jax.tree.leaves(template)),
+        tokens_per_shard * 4,  # int32 dataset shard
+    )
+    os.makedirs(args.workdir, exist_ok=True)
+    sea = Sea(checkpoint_sea_config(
+        args.workdir, max_file_size=largest + _NPY_HEADER_ROOM, n_procs=2
+    )).start()
+
+    # ---- dataset (build once; later runs reuse the persistent copy) --------
+    ds_meta = os.path.join(sea.fs.mount, "dataset", "corpus", "meta.json")
+    if not sea.fs.exists(ds_meta):
+        log(f"[data] writing {args.n_shards} shards through Sea")
+        write_dataset(
+            sea, "corpus",
+            n_shards=args.n_shards,
+            tokens_per_shard=tokens_per_shard,
+            vocab_size=cfg.vocab_size,
+        )
 
     ckpt = CheckpointManager(sea, keep_n=3)
     hb = HeartbeatMonitor(os.path.join(sea.fs.mount, "heartbeats"), 0, fs=sea.fs)
 
-    template = jax.eval_shape(init_state, jax.ShapeDtypeStruct((2,), jnp.uint32))
     start_step, state = ckpt.restore_latest(template)
     if state is None:
-        state = init_state(jax.random.PRNGKey(0))
+        state = jax.jit(init_state)(jax.random.PRNGKey(0))
         start_step = 0
-        log(f"[init] fresh start: {cfg.name}, "
+        log(f"[init] fresh start: {cfg.name}, {cfg.n_layers} layers, "
             f"{sum(x.size for x in jax.tree.leaves(state['params'])):,} params")
     else:
         log(f"[init] resumed from checkpoint step {start_step}")
@@ -142,7 +171,7 @@ def main(argv=None) -> dict:
         start_shard=0,
     )
     it = pipe.device_iter()   # batches arrive already device_put
-    losses = []
+    losses, step_s = [], []
     t_start = time.time()
     try:
         for step in range(start_step, args.steps):
@@ -154,13 +183,15 @@ def main(argv=None) -> dict:
                                     seq_len=args.seq)
                 it = pipe.device_iter()
                 batch = next(it)
-            t0 = time.time()
+            t0 = time.perf_counter()
             state, metrics = train_step(state, batch)
+            jax.block_until_ready(state)
+            step_s.append(time.perf_counter() - t0)
             loss = float(metrics["loss"])
             losses.append(loss)
             hb.beat(step)
             if not args.quiet and (step % 10 == 0 or step == args.steps - 1):
-                toks = args.batch * args.seq / (time.time() - t0)
+                toks = args.batch * args.seq / step_s[-1]
                 log(f"[step {step:5d}] loss={loss:.4f} "
                     f"gnorm={float(metrics['grad_norm']):.2f} tok/s={toks:,.0f}")
             if args.simulate_failure and step + 1 == args.simulate_failure:
@@ -181,14 +212,17 @@ def main(argv=None) -> dict:
     sea.shutdown()   # final flush: checkpoints materialize on the PFS tier
     wall = time.time() - t_start
     result = {
-        "final_loss": losses[-1] if losses else None,
-        "first_loss": losses[0] if losses else None,
+        "losses": losses,
+        "start_step": start_step,
         "steps": len(losses),
+        "step_s": step_s,
         "wall_s": wall,
+        # free bytes placement requires of a root before it admits a write
+        "admission_bytes": sea.fs.config.n_procs * sea.fs.config.max_file_size,
         "telemetry": sea.fs.telemetry.snapshot(),
     }
     log(f"[done] {len(losses)} steps in {wall:.0f}s; "
-        f"loss {result['first_loss']:.3f} -> {result['final_loss']:.3f}")
+        f"loss {losses[0]:.3f} -> {losses[-1]:.3f}")
     return result
 
 

@@ -1,21 +1,15 @@
 """Shared test plumbing.
 
-Ensures the tests directory itself is importable so test modules can fall
-back to the local ``_hypothesis_stub`` when `hypothesis` is not installed
-(the container's tier-1 environment does not ship it), and — under
-``SEACHECK=1`` — arms the seacheck runtime lock-order detector *before*
-any test module imports ``repro`` (dataclass ``default_factory=
-threading.Lock`` binds the factory at class-creation time, so the patch
-must win that race).
+Puts ``tools/`` on the path and — under ``SEACHECK=1`` — arms the
+seacheck runtime lock-order detector *before* any test module imports
+``repro`` (dataclass ``default_factory=threading.Lock`` binds the
+factory at class-creation time, so the patch must win that race).
 """
 
 import os
 import sys
 
 _HERE = os.path.dirname(os.path.abspath(__file__))
-if _HERE not in sys.path:
-    sys.path.insert(0, _HERE)
-
 _TOOLS = os.path.join(os.path.dirname(_HERE), "tools")
 if _TOOLS not in sys.path:
     sys.path.insert(0, _TOOLS)

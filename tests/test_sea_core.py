@@ -4,12 +4,8 @@ import os
 import threading
 
 import pytest
-
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # optional dependency: property tests skip cleanly
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core import (
     Mode,

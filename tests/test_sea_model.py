@@ -2,12 +2,8 @@
 validated against the paper's reported results (§4)."""
 
 import pytest
-
-try:
-    from hypothesis import given, settings
-    from hypothesis import strategies as st
-except ImportError:  # optional dependency: property tests skip cleanly
-    from _hypothesis_stub import given, settings, st
+from hypothesis import given, settings
+from hypothesis import strategies as st
 
 from repro.core.model import (
     ClusterSpec,
