@@ -58,13 +58,20 @@ _HELD_LEADER_LOCKS: set[str] = set()
 _HELD_LEADER_LOCKS_GUARD = threading.Lock()
 
 
+def _since(stamp_ns: int) -> int:
+    """Nanoseconds since ``stamp_ns`` (0 where the stamp is 0: unmeasured)."""
+    return time.perf_counter_ns() - stamp_ns if stamp_ns else 0
+
+
 class Flusher:
     def __init__(self, fs: SeaFS):
         self.fs = fs
         self.config = fs.config
         self.n_workers = max(1, int(getattr(fs.config, "flush_workers", 1)))
         self._q: "queue.Queue[str | None]" = queue.Queue()
-        self._pending: set[str] = set()   # keys queued but not yet picked up
+        #: keys queued but not yet picked up -> submit time (perf_counter_ns,
+        #: 0 while spans are off), read back as the ``flush.queued`` span
+        self._pending: dict[str, int] = {}
         self._active: dict[str, bool] = {}  # being processed -> resubmit flag
         self._deferred: set[str] = set()  # skipped busy; await any close
         self._failed: dict[str, float] = {}  # key -> monotonic not-before:
@@ -374,6 +381,7 @@ class Flusher:
         if self._coordinated and not self.is_leader:
             self._spool_submit(key)
             return
+        stamp = time.perf_counter_ns() if self.fs.telemetry.spans_on else 0
         with self._cv:
             if key in self._active:
                 # a worker is processing this key right now: flag it for
@@ -383,7 +391,7 @@ class Flusher:
                 return
             if key in self._pending:
                 return
-            self._pending.add(key)
+            self._pending[key] = stamp
         self._q.put(key)
 
     def scan(self) -> int:
@@ -425,12 +433,12 @@ class Flusher:
                     break
                 continue  # stale sentinel from a previous stop()
             with self._cv:
-                self._pending.discard(key)
+                stamp = self._pending.pop(key, 0)
                 self._active[key] = False
                 self._inflight += 1
             try:
                 try:
-                    self.process(key)
+                    self.process(key, queued_ns=_since(stamp))
                 except Exception as e:
                     # a failed flush (exhausted transfer retries, device
                     # error) must not kill the worker thread — but it
@@ -456,7 +464,7 @@ class Flusher:
                 with self._cv:
                     if self._active.pop(key, False):
                         # a submit arrived mid-process: queue one more pass
-                        self._pending.add(key)
+                        self._pending[key] = 0
                         requeue = True
                     self._inflight -= 1
                     self._cv.notify_all()
@@ -495,19 +503,22 @@ class Flusher:
             if key is None:
                 continue
             with self._cv:
-                self._pending.discard(key)
+                stamp = self._pending.pop(key, 0)
                 self._active[key] = False
-            self.process(key)
+            self.process(key, queued_ns=_since(stamp))
             requeue = False
             with self._cv:
                 if self._active.pop(key, False):
-                    self._pending.add(key)
+                    self._pending[key] = 0
                     requeue = True
             if requeue:
                 self._q.put(key)
 
     # -- the four modes ------------------------------------------------------------
-    def process(self, key: str) -> Mode:
+    def process(self, key: str, queued_ns: int = 0) -> Mode:
+        """Flush and/or evict ``key`` as its mode says. ``queued_ns``: how
+        long it waited in the queue, recorded as ``flush.queued`` if it
+        moves (0: not measured)."""
         mode = self.fs.rules.mode(key)
         if mode is Mode.KEEP:
             return mode
@@ -527,25 +538,31 @@ class Flusher:
             tier, real = located
             if tier.persistent:
                 return mode  # already on long-term storage: nothing to do
-            if mode in (Mode.COPY, Mode.MOVE):
-                self._flush_one(key, real, tier)
-            if mode in (Mode.MOVE, Mode.REMOVE):
-                if not self._draining and self.fs.prefetcher.is_hot(key):
-                    # predicted-hot: the readahead engine staged (or is
-                    # staging) this key because the application is about
-                    # to read it — evicting now would throw that work
-                    # away. The flush above still ran; the evict retries
-                    # on an idle tick once the hotness expires. drain()
-                    # ignores hotness: shutdown durability wins.
-                    with self._cv:
-                        self._failed.setdefault(
-                            key, time.monotonic() + 2 * self._hb_interval
-                        )
-                    return mode
-                self._evict_one(key, real, tier)
+            telemetry = self.fs.telemetry
+            if queued_ns:
+                telemetry.record_span("flush.queued", queued_ns / 1e9)
+            with telemetry.span("flush.move") as span:
+                if mode in (Mode.COPY, Mode.MOVE):
+                    span.nbytes = self._flush_one(key, real, tier)
+                if mode in (Mode.MOVE, Mode.REMOVE):
+                    if not self._draining and self.fs.prefetcher.is_hot(key):
+                        # predicted-hot: the readahead engine staged (or is
+                        # staging) this key because the application is about
+                        # to read it — evicting now would throw that work
+                        # away. The flush above still ran; the evict retries
+                        # on an idle tick once the hotness expires. drain()
+                        # ignores hotness: shutdown durability wins.
+                        with self._cv:
+                            self._failed.setdefault(
+                                key, time.monotonic() + 2 * self._hb_interval
+                            )
+                        return mode
+                    span.nbytes = self._evict_one(key, real, tier) or span.nbytes
         return mode
 
-    def _flush_one(self, key: str, src: str, src_tier=None) -> None:
+    def _flush_one(self, key: str, src: str, src_tier=None) -> int:
+        """Copy ``key`` to the base tier; returns the bytes copied (0: the
+        base copy was already fresh, or the source vanished)."""
         base = self.fs.hierarchy.base
         base_root = base.roots[0]
         dst = os.path.join(base_root, key)
@@ -553,7 +570,7 @@ class Flusher:
         try:
             sst = os.stat(src)
         except OSError:
-            return  # vanished under the key lock's last release: nothing to do
+            return 0  # vanished under the key lock's last release: nothing to do
         try:
             dst_st = os.stat(dst)
         except OSError:
@@ -568,7 +585,7 @@ class Flusher:
             # sources rewritten within one mtime tick of the last flush.
             # The engine copystats the source onto the committed copy, so
             # equality here means byte-for-byte freshness.
-            return
+            return 0
         # the flusher only ever drains *away from* cache roots: the
         # destination is always the base tier, which the breaker never
         # quarantines — a sick root's files still reach durability while
@@ -584,8 +601,10 @@ class Flusher:
             admit="reserve",
         )
         self.fs.telemetry.record_flush(result.nbytes)
+        return result.nbytes
 
-    def _evict_one(self, key: str, src: str, tier) -> None:
+    def _evict_one(self, key: str, src: str, tier) -> int:
+        """Remove the cache copy of ``key``; returns its bytes (0: failed)."""
         try:
             nbytes = os.path.getsize(src)
             os.remove(src)
@@ -597,8 +616,9 @@ class Flusher:
             self.fs.resolver.invalidate(key)
             self.fs._fed_unpublish(key)
             self.fs.telemetry.record_evict(nbytes)
+            return nbytes
         except OSError:
-            pass
+            return 0
 
     # -- prefetch -----------------------------------------------------------------
     def prefetch(self) -> int:

@@ -30,7 +30,7 @@ from .lists import CompiledRules, Mode
 from .placement import PlacementPolicy
 from .prefetcher import Prefetcher
 from .resolver import Resolver
-from .telemetry import Stopwatch, Telemetry
+from .telemetry import Telemetry
 from .tiers import Hierarchy, Tier
 from .transfer import TransferEngine
 
@@ -49,9 +49,21 @@ def _is_write_mode(mode: str) -> bool:
     return any(c in mode for c in _WRITE_CHARS)
 
 
+def _spanned(span, fn, *args):
+    """``fn(*args)`` inside ``span``; the span's bytes are the returned
+    count or the length of the returned data."""
+    with span:
+        out = fn(*args)
+        span.nbytes = out if isinstance(out, int) else len(out or b"")
+    return out
+
+
 class _SeaFile:
     """Proxy around a real file object: forwards everything, and notifies
     SeaFS on close so the flush-and-evict daemon can pick the file up.
+    ``read``/``readinto``/``readline`` and ``write`` are real methods, so
+    that each call is a ``sea.read`` or ``sea.write`` span while spans are
+    on (one attribute check while they are off).
     Open files are refcounted — the flusher never moves a busy file
     (beyond-paper fix for the paper's §5.5 known limitation). A write
     handle additionally carries its capacity reservation, committed (with
@@ -76,7 +88,10 @@ class _SeaFile:
         self._real = real
         self._reservation = reservation
         self._fast = fast
-        self._t0 = time.perf_counter()
+        self._tel = fs.telemetry
+        # open-to-close time of a write handle: the health evidence its
+        # commit records
+        self._t0 = time.perf_counter() if writing else 0.0
         self._closed = False
         self._fd = None
         if writing:
@@ -98,10 +113,30 @@ class _SeaFile:
     def __getattr__(self, name):
         return getattr(self._raw, name)
 
+    def read(self, *args):
+        if self._tel.spans_on and not self._writing:
+            return _spanned(self._tel.span("sea.read"), self._raw.read, *args)
+        return self._raw.read(*args)
+
+    def readinto(self, b):
+        if self._tel.spans_on and not self._writing:
+            return _spanned(self._tel.span("sea.read"), self._raw.readinto, b)
+        return self._raw.readinto(b)
+
+    def readline(self, *args):
+        if self._tel.spans_on and not self._writing:
+            return _spanned(self._tel.span("sea.read"), self._raw.readline, *args)
+        return self._raw.readline(*args)
+
     def write(self, data):
-        raw = self._raw
         if not self._writing:
-            return raw.write(data)
+            return self._raw.write(data)
+        if self._tel.spans_on:
+            return _spanned(self._tel.span("sea.write"), self._write, data)
+        return self._write(data)
+
+    def _write(self, data):
+        raw = self._raw
         pre_pos = None
         if not self._tier.spec.persistent:
             # logical position before the write: a large buffered write
@@ -150,7 +185,7 @@ class _SeaFile:
                 pos = 0
             self._raw.close()
         finally:
-            dt = time.perf_counter() - self._t0
+            dt = time.perf_counter() - self._t0 if self._writing else 0.0
             self._fs._on_close(
                 self._key,
                 self._tier,
@@ -453,12 +488,14 @@ class SeaFS:
                     if root is not None:
                         # overwrite in place: no admission, just hold the
                         # in-flight budget until close commits the size
-                        res = self.policy.reserve_write(tier, root)
+                        with self.telemetry.span("sea.admit"):
+                            res = self.policy.reserve_write(tier, root)
                 return tier, real, res
             make_room = self._lru_make_room if self.config.lru_evict else None
-            tier, root, res = self.policy.place_new(
-                reserve=reserve, make_room=make_room
-            )
+            with self.telemetry.span("sea.admit"):
+                tier, root, res = self.policy.place_new(
+                    reserve=reserve, make_room=make_room
+                )
             real = os.path.join(root, key)
             os.makedirs(os.path.dirname(real), exist_ok=True)
             # verified=False: the file is not materialized until the
@@ -484,6 +521,12 @@ class SeaFS:
     # -- file operations ------------------------------------------------------
     def open(self, path: str, mode: str = "r", **kw):
         writing = _is_write_mode(mode)
+        if self.telemetry.spans_on:
+            with self.telemetry.span("sea.open.write" if writing else "sea.open.read"):
+                return self._open(path, mode, writing, kw)
+        return self._open(path, mode, writing, kw)
+
+    def _open(self, path: str, mode: str, writing: bool, kw: dict):
         if not writing:
             f = self._open_read_fast(path, mode, kw)
             if f is not None:
@@ -858,34 +901,46 @@ class SeaFS:
         fast: bool = False,
     ):
         if writing:
-            if real is not None:
-                # commit the actual on-disk size against the reservation
-                # BEFORE dropping the open-count: once the count hits zero
-                # the flusher may evict the file, and a late commit would
-                # resurrect a ghost ledger entry.
-                root = tier.root_of(real)
-                try:
-                    actual = os.path.getsize(real)
-                except OSError:
-                    actual = max(nbytes, 0)
-                if root is not None:
-                    self.policy.commit_write(tier, reservation, root, key, actual)
-                else:
-                    self.policy.release_write(tier, reservation)
-                self.resolver.note_location(key, tier, real)
-                if root is not None and not tier.persistent:
-                    self._fed_publish(key, root, actual)
-                    # a committed application write is health evidence —
-                    # this is what lets a half-open probe write re-admit
-                    # a recovered root
-                    self.health.record_success(root, dt)
-            self.telemetry.record_io(tier.name, written=max(nbytes, 0), seconds=dt)
-        elif fast:
+            with self.telemetry.span("sea.close"):
+                self._commit_write(key, tier, nbytes, dt, real, reservation)
+                self._release_open(key, writing)
+            return
+        if fast:
             # fast-path reads batch their I/O counters per thread — no
             # telemetry mutex on the hot close either
-            self.telemetry.local().record_read(tier.name, max(nbytes, 0), dt)
+            self.telemetry.local().record_read(tier.name, max(nbytes, 0))
         else:
-            self.telemetry.record_io(tier.name, read=max(nbytes, 0), seconds=dt)
+            self.telemetry.record_io(tier.name, read=max(nbytes, 0))
+        self._release_open(key, writing)
+
+    def _commit_write(self, key: str, tier: Tier, nbytes: int, dt: float,
+                      real: str | None, reservation) -> None:
+        if real is not None:
+            # commit the actual on-disk size against the reservation
+            # BEFORE dropping the open-count: once the count hits zero
+            # the flusher may evict the file, and a late commit would
+            # resurrect a ghost ledger entry.
+            root = tier.root_of(real)
+            try:
+                actual = os.path.getsize(real)
+            except OSError:
+                actual = max(nbytes, 0)
+            if root is not None:
+                self.policy.commit_write(tier, reservation, root, key, actual)
+            else:
+                self.policy.release_write(tier, reservation)
+            self.resolver.note_location(key, tier, real)
+            if root is not None and not tier.persistent:
+                self._fed_publish(key, root, actual)
+                # a committed application write is health evidence —
+                # this is what lets a half-open probe write re-admit
+                # a recovered root
+                self.health.record_success(root, dt)
+        self.telemetry.record_io(tier.name, written=max(nbytes, 0))
+
+    def _release_open(self, key: str, writing: bool) -> None:
+        """Drop one open of ``key``; the last close notifies the close
+        listeners (the flusher)."""
         with self._lock:
             if writing:
                 self._drop_writer(key)  # self._lock is reentrant
@@ -906,10 +961,8 @@ class SeaFS:
         ):
             if self._write_striped(path, data):
                 return path
-        with Stopwatch() as sw:
-            with self.open(path, "wb") as f:
-                f.write(data)
-        del sw
+        with self.open(path, "wb") as f:
+            f.write(data)
         return path
 
     def read_bytes(self, path: str) -> bytes:

@@ -1,8 +1,16 @@
-"""Per-tier telemetry counters.
+"""Per-tier telemetry counters and spans.
 
 Lightweight, thread-safe counters so benchmarks and the framework can see
 where bytes actually went (tier hit ratios, flush/evict volumes). Purely
 observational — placement never consults telemetry (Sea stays stateless).
+
+Spans time the layers where the work happens (the ``SPANS`` registry):
+off by default, where a span site costs one attribute check and reads no
+clock; :meth:`Telemetry.trace_spans` turns them on. Each span then adds
+its count, wall and thread-CPU nanoseconds and bytes to a per-thread,
+lock-free block, and — where ``jax`` is already imported — is also a
+``jax.profiler.TraceAnnotation``, so a profiler trace shows it on the
+device trace's clock. This module never imports ``jax`` itself.
 
 Counters are **per-process**: with ``shared_ledger`` deployments every Sea
 instance exports its snapshot to ``<base_root>/.sea_ledger/telemetry/`` at
@@ -14,6 +22,7 @@ from __future__ import annotations
 
 import json
 import os
+import sys
 import threading
 import time
 from collections import defaultdict
@@ -26,8 +35,6 @@ class TierCounters:
     bytes_read: int = 0
     files_written: int = 0
     files_read: int = 0
-    read_seconds: float = 0.0
-    write_seconds: float = 0.0
 
 
 @dataclass
@@ -41,31 +48,114 @@ class TransferCounters:
 
 
 class ThreadCounters:
-    """Per-thread counter block for the open fast path: plain int/float
-    increments with **no lock at all** (each block is written by exactly
-    one thread; CPython attribute stores are GIL-atomic). ``snapshot``
+    """Per-thread counter block for the open fast path and for spans:
+    plain int increments with **no lock at all** (each block is written by
+    exactly one thread; CPython attribute stores are GIL-atomic). ``snapshot``
     folds the live blocks in non-destructively — counters only grow, so
     summing base + per-thread values is always an under-by-at-most-one
     -in-flight-increment view and exact once threads quiesce. Blocks of
     dead threads are folded into the base counters and dropped, so
     thread churn cannot grow the registry without bound."""
 
-    __slots__ = ("owner", "redirect_hits", "fastpath_opens", "io_read")
+    __slots__ = ("owner", "redirect_hits", "fastpath_opens", "io_read", "spans")
 
     def __init__(self):
         self.owner = threading.current_thread()
         self.redirect_hits = 0
         self.fastpath_opens = 0
-        #: tier -> [bytes_read, files_read, read_seconds]
+        #: tier -> [bytes_read, files_read]
         self.io_read: dict[str, list] = {}
+        #: span name -> [count, wall_ns, cpu_ns, bytes]
+        self.spans: dict[str, list] = {}
 
-    def record_read(self, tier: str, nbytes: int, seconds: float) -> None:
+    def record_read(self, tier: str, nbytes: int) -> None:
         c = self.io_read.get(tier)
         if c is None:
-            c = self.io_read[tier] = [0, 0, 0.0]
+            c = self.io_read[tier] = [0, 0]
         c[0] += nbytes
         c[1] += 1
-        c[2] += seconds
+
+    def add_span(self, name: str, wall_ns: int, cpu_ns: int, nbytes: int) -> None:
+        c = self.spans.get(name)
+        if c is None:
+            c = self.spans[name] = [0, 0, 0, 0]
+        c[0] += 1
+        c[1] += wall_ns
+        c[2] += cpu_ns
+        c[3] += nbytes
+
+
+#: The span registry: every span a Sea layer records, with its parent span
+#: (None: a root) and what it times. ``snapshot()["spans"]`` carries every
+#: name here; ``seacheck``'s telemetry-drift rule checks that each literal
+#: name passed to ``span``/``record_span`` is a key and each key is used.
+SPANS: dict[str, tuple[str | None, str]] = {
+    "sea.open.read": (None, "SeaFS.open of a read handle, fast or slow path"),
+    "sea.open.write": (None, "SeaFS.open of a write handle: resolution, admission, the open"),
+    "sea.admit": ("sea.open.write", "placement admission: place_new or reserve_write"),
+    "sea.read": (None, "one read/readinto/readline call of a read handle; bytes returned"),
+    "sea.write": (None, "one write call of a write handle; bytes written"),
+    "sea.close": (None, "write-handle commit on close: size, ledger, resolver, health, "
+                        "federation, close listeners"),
+    "flush.queued": (None, "a flushed or evicted key's wait from Flusher.submit to pickup"),
+    "flush.move": (None, "Flusher.process of a cache-resident key past its busy check: "
+                         "the flush and/or evict; bytes moved"),
+    "feed.put": (None, "device_iter's feeder thread putting one batch (put_fn)"),
+    "feed.wait": (None, "device_iter's consumer blocked on an empty feed"),
+}
+
+
+class _Span:
+    """One live span (spans on): times its ``with`` block on the wall and
+    thread-CPU clocks and adds both, with ``nbytes``, to this thread's
+    block; a TraceAnnotation of the same name where jax is imported."""
+
+    __slots__ = ("_tel", "name", "nbytes", "_ann", "_t0", "_c0")
+
+    def __init__(self, tel: "Telemetry", name: str, nbytes: int):
+        self._tel = tel
+        self.name = name
+        self.nbytes = nbytes
+
+    def __enter__(self) -> "_Span":
+        profiler = getattr(sys.modules.get("jax"), "profiler", None)
+        self._ann = ann = profiler.TraceAnnotation(self.name) if profiler else None
+        if ann is not None:
+            ann.__enter__()
+        self._c0 = time.thread_time_ns()
+        self._t0 = time.perf_counter_ns()
+        return self
+
+    def __exit__(self, *exc) -> None:
+        t1 = time.perf_counter_ns()
+        c1 = time.thread_time_ns()
+        if self._ann is not None:
+            self._ann.__exit__(None, None, None)
+        self._tel.local().add_span(self.name, t1 - self._t0, c1 - self._c0, self.nbytes)
+
+
+class _NoSpan:
+    """The span of a site while spans are off: enters and exits with no
+    clock and no lock; ``nbytes`` set on it goes nowhere."""
+
+    __slots__ = ()
+
+    def __enter__(self) -> "_NoSpan":
+        return self
+
+    def __exit__(self, *exc) -> None:
+        return None
+
+    @property
+    def nbytes(self) -> int:
+        return 0
+
+    @nbytes.setter
+    def nbytes(self, value: int) -> None:
+        pass
+
+
+_NO_SPAN = _NoSpan()
 
 
 #: The canonical counter registry: every scalar counter ``Telemetry``
@@ -200,23 +290,23 @@ class Telemetry:
                                     # happened within transfer_deadline_s
     hung_thread_joins: int = 0      # stop() joins that timed out with the
                                     # worker thread still alive
+    #: span sites record only while this is set (``trace_spans``)
+    spans_on: bool = False
     _lock: threading.Lock = field(default_factory=threading.Lock, repr=False)
     _tls: threading.local = field(default_factory=threading.local, repr=False)
     _locals: list = field(default_factory=list, repr=False)
+    #: span name -> [count, wall_ns, cpu_ns, bytes] of dead threads' blocks
+    _span_totals: dict = field(default_factory=dict, repr=False)
 
-    def record_io(
-        self, tier: str, *, read: int = 0, written: int = 0, seconds: float = 0.0
-    ) -> None:
+    def record_io(self, tier: str, *, read: int = 0, written: int = 0) -> None:
         with self._lock:
             c = self.per_tier[tier]
             if read:
                 c.bytes_read += read
                 c.files_read += 1
-                c.read_seconds += seconds
             if written:
                 c.bytes_written += written
                 c.files_written += 1
-                c.write_seconds += seconds
 
     def record_transfer(
         self, pair: str, *, nbytes: int, seconds: float = 0.0, retries: int = 0
@@ -393,6 +483,25 @@ class Telemetry:
         with self._lock:
             self.hung_thread_joins += 1
 
+    # -- spans -----------------------------------------------------------------
+    def trace_spans(self, on: bool = True) -> None:
+        """Turn span recording on or off (off by default)."""
+        self.spans_on = bool(on)
+
+    def span(self, name: str, nbytes: int = 0):
+        """Context manager timing one ``name`` span (a ``SPANS`` key) on
+        this thread; set ``.nbytes`` on it where the bytes are known only
+        at the end. With spans off it costs one attribute check."""
+        if not self.spans_on:
+            return _NO_SPAN
+        return _Span(self, name, nbytes)
+
+    def record_span(self, name: str, seconds: float) -> None:
+        """One ``name`` span of ``seconds`` on the wall clock, measured by
+        the caller, for a duration that crosses threads (no CPU time)."""
+        if self.spans_on:
+            self.local().add_span(name, int(seconds * 1e9), 0, 0)
+
     # -- thread-batched fast-path counters ----------------------------------
     def local(self) -> ThreadCounters:
         """This thread's lock-free counter block (created and registered
@@ -421,11 +530,11 @@ class Telemetry:
             self.redirect_hits += lc.redirect_hits
             self.fastpath_redirect_hits += lc.redirect_hits
             self.fastpath_opens += lc.fastpath_opens
-            for tier, (nbytes, files, seconds) in lc.io_read.items():
+            for tier, (nbytes, files) in lc.io_read.items():
                 c = self.per_tier[tier]
                 c.bytes_read += nbytes
                 c.files_read += files
-                c.read_seconds += seconds
+            _add_spans(self._span_totals, lc.spans)
         self._locals = live
 
     def snapshot(self) -> dict:
@@ -441,6 +550,7 @@ class Telemetry:
             }
             for name in COUNTERS:
                 snap[name] = getattr(self, name)
+            spans = {name: list(c) for name, c in self._span_totals.items()}
             locals_ = list(self._locals)
         # fold the LIVE per-thread fast-path blocks in (non-destructive
         # sums: the blocks only grow and are never reset, so no event is
@@ -452,22 +562,17 @@ class Telemetry:
             snap["fastpath_redirect_hits"] += lc.redirect_hits
             live_redirects += lc.redirect_hits
             for tier in tuple(lc.io_read):
-                nbytes, files, seconds = lc.io_read[tier]
-                c = snap["tiers"].setdefault(
-                    tier,
-                    {
-                        "bytes_written": 0,
-                        "bytes_read": 0,
-                        "files_written": 0,
-                        "files_read": 0,
-                        "read_seconds": 0.0,
-                        "write_seconds": 0.0,
-                    },
-                )
+                nbytes, files = lc.io_read[tier]
+                c = snap["tiers"].setdefault(tier, vars(TierCounters()).copy())
                 c["bytes_read"] += nbytes
                 c["files_read"] += files
-                c["read_seconds"] += seconds
+            _add_spans(spans, dict(lc.spans))
         snap["redirect_hits"] += live_redirects
+        snap["spans"] = {}
+        for name in {**SPANS, **spans}:
+            n, wall, cpu, nbytes = spans.get(name, (0, 0, 0, 0))
+            snap["spans"][name] = {"count": n, "wall_s": wall / 1e9, "cpu_s": cpu / 1e9,
+                                   "bytes": nbytes}
         return snap
 
     def export(self, path: str) -> str:
@@ -484,25 +589,33 @@ class Telemetry:
         return path
 
 
+def _add_spans(into: dict, blocks: dict) -> None:
+    """Add per-name ``[count, wall_ns, cpu_ns, bytes]`` blocks into ``into``."""
+    for name, c in blocks.items():
+        t = into.setdefault(name, [0, 0, 0, 0])
+        for i, v in enumerate(c):
+            t[i] += v
+
+
 def aggregate_snapshots(snapshots: list[dict]) -> dict:
     """Merge per-process snapshots into one aggregate view: numeric
     counters sum (per tier and global); pids are collected for attribution."""
-    agg: dict = {"tiers": {}, "transfers": {}, "pids": []}
+    agg: dict = {"tiers": {}, "transfers": {}, "spans": {}, "pids": []}
     for snap in snapshots:
         if "pid" in snap:
             agg["pids"].append(snap["pid"])
-        for section in ("tiers", "transfers"):
+        for section in ("tiers", "transfers", "spans"):
             for name, counters in snap.get(section, {}).items():
                 out = agg[section].setdefault(name, defaultdict(float))
                 for k, v in counters.items():
                     out[k] += v
         for k, v in snap.items():
-            if k in ("tiers", "transfers", "pid", "exported_at"):
+            if k in ("tiers", "transfers", "spans", "pid", "exported_at"):
                 continue
             if isinstance(v, (int, float)):
                 agg[k] = agg.get(k, 0) + v
-    agg["tiers"] = {t: dict(c) for t, c in agg["tiers"].items()}
-    agg["transfers"] = {t: dict(c) for t, c in agg["transfers"].items()}
+    for section in ("tiers", "transfers", "spans"):
+        agg[section] = {t: dict(c) for t, c in agg[section].items()}
     agg["pids"].sort()
     return agg
 
@@ -525,15 +638,3 @@ def load_aggregate(stats_dir: str) -> dict:
             continue
     return aggregate_snapshots(snaps)
 
-
-class Stopwatch:
-    """Context timer used around raw I/O calls."""
-
-    __slots__ = ("t0", "elapsed")
-
-    def __enter__(self) -> "Stopwatch":
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc) -> None:
-        self.elapsed = time.perf_counter() - self.t0

@@ -244,7 +244,8 @@ class DataPipeline:
         double-buffered behind compute exactly like the staging thread
         double-buffers the base->cache->host hops. ``depth`` defaults to
         the ``device_prefetch`` config knob. A consumer that finds the
-        buffer empty records a ``device_feed_stalls`` telemetry tick."""
+        buffer empty records a ``device_feed_stalls`` telemetry tick and
+        its wait as a ``feed.wait`` span; each put is a ``feed.put`` span."""
         if depth is None:
             depth = max(1, getattr(self.fs.config, "device_prefetch", 2))
         if put_fn is None:
@@ -255,11 +256,13 @@ class DataPipeline:
 
         fed: "queue.Queue" = queue.Queue(maxsize=depth)
         done = threading.Event()
+        telemetry = self.fs.telemetry
 
         def _feed() -> None:
             try:
                 for batch in self:
-                    item = (0, put_fn(batch))
+                    with telemetry.span("feed.put"):
+                        item = (0, put_fn(batch))
                     while True:
                         if done.is_set():
                             return  # consumer gone: nobody reads a sentinel
@@ -286,8 +289,9 @@ class DataPipeline:
                 except queue.Empty:
                     if done.is_set():
                         return
-                    self.fs.telemetry.record_device_feed_stall()
-                    tag, item = fed.get()
+                    telemetry.record_device_feed_stall()
+                    with telemetry.span("feed.wait"):
+                        tag, item = fed.get()
                 if tag == -2:
                     raise RuntimeError("device feed failed") from item
                 if tag == -1:

@@ -117,6 +117,39 @@ def test_telemetry_drift_flags_ad_hoc_increments():
     assert len(out) == 1 and "flushed_bytes" in out[0].message
 
 
+def test_telemetry_drift_spans_registry_and_sites():
+    with open(os.path.join(FIXTURES, "bad_spans.py")) as f:
+        src = f.read()
+    tree = ast.parse(src)
+    sf = SourceFile(path="src/repro/core/telemetry.py", source=src)
+    out = telemetry_drift.check_tree([(sf, tree)])
+    msgs = sorted(v.message for v in out)
+    assert len(out) == 2, msgs
+    assert "ghost.span" in msgs[0] and "never recorded" in msgs[0]
+    assert "stray.span" in msgs[1] and "not registered" in msgs[1]
+    # without telemetry.py in the run there is no registry to hold sites to
+    other = SourceFile(path="src/repro/core/seafs.py", source=src)
+    assert telemetry_drift.check_tree([(other, tree)]) == []
+
+
+def test_gate_reddens_on_unregistered_span(tmp_path, capsys):
+    (tmp_path / "src" / "repro" / "core").mkdir(parents=True)
+    (tmp_path / "src" / "repro" / "core" / "telemetry.py").write_text(
+        'COUNTERS = {}\nSPANS = {"sea.read": (None, "x")}\n'
+        "class Telemetry:\n    def snapshot(self):\n        return COUNTERS\n"
+    )
+    rc = _gate(
+        tmp_path,
+        "src/repro/core/fs.py",
+        "def f(tel):\n"
+        "    with tel.span('sea.read'):\n"
+        "        pass\n"
+        "    tel.record_span('sea.typo', 1.0)\n",
+    )
+    assert rc == 1
+    assert "'sea.typo' is not registered in SPANS" in capsys.readouterr().out
+
+
 def test_real_counters_registry_matches_fields():
     """The live COUNTERS table and the Telemetry dataclass agree (the
     lint rule checks this lexically; this checks it at runtime)."""

@@ -389,9 +389,9 @@ def test_sea_start_is_idempotent(tmp_path):
 # ------------------------------------------------------------------ telemetry
 def test_telemetry_aggregate_sums_processes(tmp_path):
     t1, t2 = Telemetry(), Telemetry()
-    t1.record_io("tmpfs", written=100, seconds=0.5)
+    t1.record_io("tmpfs", written=100)
     t1.record_flush(100)
-    t2.record_io("tmpfs", written=50, seconds=0.25)
+    t2.record_io("tmpfs", written=50)
     t2.record_io("pfs", read=30)
     agg = aggregate_snapshots([t1.snapshot(), t2.snapshot()])
     assert agg["tiers"]["tmpfs"]["bytes_written"] == 150

@@ -55,12 +55,21 @@ def lint_paths(
     """All unsuppressed violations over ``paths`` (baseline NOT applied)."""
     root = root or os.getcwd()
     out: list[Violation] = []
+    parsed: list = []
     for path in iter_py_files(paths):
-        out.extend(lint_file(path, root=root, rules=rules))
+        out.extend(lint_file(path, root=root, rules=rules, parsed=parsed))
+    for rule in rules:
+        check_tree = getattr(rule, "check_tree", None)
+        if check_tree is not None:
+            out.extend(check_tree(parsed))
     return out
 
 
-def lint_file(path: str, *, root: str, rules=ALL_RULES) -> list[Violation]:
+def lint_file(
+    path: str, *, root: str, rules=ALL_RULES, parsed: list | None = None
+) -> list[Violation]:
+    """One file's violations; appends ``(SourceFile, tree)`` to ``parsed``
+    (when given) for the rules that check across files."""
     try:
         with open(path, encoding="utf-8") as f:
             source = f.read()
@@ -81,6 +90,8 @@ def lint_file(path: str, *, root: str, rules=ALL_RULES) -> list[Violation]:
         ]
     annotate_parents(tree)
     sf = SourceFile(path=relpath(path, root), source=source)
+    if parsed is not None:
+        parsed.append((sf, tree))
     out: list[Violation] = []
     for rule in rules:
         out.extend(rule.check(sf, tree))

@@ -2,7 +2,9 @@
 
 Each rule module exposes ``RULE_ID`` (kebab-case), ``RULE_DOC`` (one-line
 summary) and ``check(sf, tree) -> list[Violation]``. The engine parses each
-file once, annotates parent links, and hands the tree to every rule.
+file once, annotates parent links, and hands the tree to every rule. A rule
+that checks across files also exposes ``check_tree(files)``, called once
+with every ``(sf, tree)`` of the run.
 """
 
 from __future__ import annotations

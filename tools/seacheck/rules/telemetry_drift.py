@@ -20,6 +20,11 @@ no imports):
 Check everywhere else: counters are mutated only through
 ``Telemetry.record_*`` methods — a ``<x>.telemetry.<counter> += ...`` spot
 increment bypasses the lock and the registry and is flagged.
+
+Across files (``check_tree``, where the run lints ``telemetry.py``): every
+literal span name passed to ``<x>.span(...)`` or ``<x>.record_span(...)``
+in a ``repro`` module is a key of the ``SPANS`` registry, and every
+``SPANS`` key is passed somewhere.
 """
 
 from __future__ import annotations
@@ -32,8 +37,10 @@ from ..violations import SourceFile, Violation
 RULE_ID = "telemetry-drift"
 RULE_DOC = (
     "every incremented Telemetry counter must be registered in COUNTERS "
-    "and vice versa"
+    "and vice versa; every span name in SPANS and vice versa"
 )
+
+SPAN_CALLS = ("span", "record_span")
 
 TELEMETRY_SUFFIX = "repro/core/telemetry.py"
 
@@ -45,9 +52,9 @@ def _find_class(tree: ast.AST, name: str) -> ast.ClassDef | None:
     return None
 
 
-def _counters_table(tree: ast.AST) -> tuple[dict[str, int], int]:
-    """``{counter_name: lineno}`` from the module-level COUNTERS dict
-    literal, plus the table's own line (0 when absent)."""
+def _counters_table(tree: ast.AST, table: str = "COUNTERS") -> tuple[dict[str, int], int]:
+    """``{name: lineno}`` from the module-level ``table`` dict literal
+    (COUNTERS or SPANS), plus the table's own line (0 when absent)."""
     for node in ast.walk(tree):
         target = None
         if isinstance(node, ast.AnnAssign) and isinstance(
@@ -60,7 +67,7 @@ def _counters_table(tree: ast.AST) -> tuple[dict[str, int], int]:
         ):
             target = node.targets[0].id
             value = node.value
-        if target != "COUNTERS":
+        if target != table:
             continue
         if not isinstance(value, ast.Dict):
             return {}, node.lineno
@@ -190,3 +197,49 @@ def check(sf: SourceFile, tree: ast.AST) -> list[Violation]:
     if sf.path.endswith(TELEMETRY_SUFFIX):
         return _check_telemetry_module(sf, tree)
     return _check_other_module(sf, tree)
+
+
+def _span_names(tree: ast.AST) -> list[tuple[str, int]]:
+    """``(name, lineno)`` of every string literal in the first argument of
+    a ``.span(...)`` or ``.record_span(...)`` call (both arms of a
+    conditional name count)."""
+    out = []
+    for node in ast.walk(tree):
+        if (
+            isinstance(node, ast.Call)
+            and isinstance(node.func, ast.Attribute)
+            and node.func.attr in SPAN_CALLS
+            and node.args
+        ):
+            out.extend(
+                (n.value, n.lineno)
+                for n in ast.walk(node.args[0])
+                if isinstance(n, ast.Constant) and isinstance(n.value, str)
+            )
+    return out
+
+
+def check_tree(files: list[tuple[SourceFile, ast.AST]]) -> list[Violation]:
+    registry = next(
+        ((sf, tree) for sf, tree in files if sf.path.endswith(TELEMETRY_SUFFIX)),
+        None,
+    )
+    if registry is None:
+        return []  # the run does not lint telemetry.py: nothing to hold to
+    reg_sf, reg_tree = registry
+    spans, _ = _counters_table(reg_tree, "SPANS")
+    out: list[Violation] = []
+    used: set[str] = set()
+    for sf, tree in files:
+        if "repro/" not in sf.path:
+            continue
+        for name, line in _span_names(tree):
+            used.add(name)
+            if name not in spans and not sf.suppressed(line, RULE_ID):
+                out.append(Violation(RULE_ID, sf.path, line, "<span>",
+                                     f"span {name!r} is not registered in SPANS"))
+    for name, line in spans.items():
+        if name not in used and not reg_sf.suppressed(line, RULE_ID):
+            out.append(Violation(RULE_ID, reg_sf.path, line, "SPANS",
+                                 f"registered span {name!r} is never recorded"))
+    return out
