@@ -1,10 +1,15 @@
-"""GQA attention: flash-style chunked online-softmax in pure XLA, sliding
-windows, KV caches (full + ring-buffer for local layers), decode paths.
+"""GQA attention: a fused causal kernel on TPU, flash-style chunked
+online-softmax in pure XLA elsewhere, sliding windows, KV caches (full +
+ring-buffer for local layers), decode paths.
 
-The chunked path is the XLA twin of the Pallas flash kernel
-(``repro.kernels.flash_attention``) and doubles as its oracle at small
-sizes. Scores/softmax statistics accumulate in fp32; the P·V matmul runs
-in the compute dtype for the MXU.
+Training self-attention (``attn_apply``) runs JAX's Pallas splash kernel
+(``fused_causal_attention``) on TPU when the call is plain causal, unsharded
+and of a tileable shape; the score tile stays in VMEM, blocks above the
+diagonal are skipped, and its custom VJP is the backward pass. Every other
+call (the CPU, windows, softcaps, cross-attention, prefill, a sharded mesh)
+runs ``chunked_attention``, which also serves as the oracle of the Pallas
+kernels at small sizes. Scores/softmax statistics accumulate in fp32; the
+chunked P·V matmul runs in the compute dtype for the MXU.
 """
 
 from __future__ import annotations
@@ -14,9 +19,10 @@ from dataclasses import dataclass
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from repro.configs.base import AttentionConfig
-from repro.distributed.sharding import DP, FSDP, TP, shard_hint
+from repro.distributed.sharding import DP, FSDP, TP, current_rules, shard_hint
 from repro.models.layers import (
     Layout,
     apply_rope,
@@ -132,6 +138,64 @@ def chunked_attention(
     return out[:, :Sq]
 
 
+def _splash_block(S: int) -> int | None:
+    """The largest tile of 512, 256 or 128 rows that divides S."""
+    return next((b for b in (512, 256, 128) if S % b == 0), None)
+
+
+def fused_causal_attention(
+    q: jax.Array,            # [B, S, H, Dh]
+    k: jax.Array,            # [B, S, Hk, Dh]
+    v: jax.Array,            # [B, S, Hk, Dh]
+    *,
+    interpret: bool = False,
+) -> jax.Array:
+    """Causal self-attention as JAX's Pallas splash kernel (TPU).
+
+    One MQA kernel of G = H/Hk query heads per KV head, vmapped over batch
+    and KV heads, so GQA needs no repeat of K/V. Blocks above the diagonal
+    are skipped; the custom VJP recomputes the tiles in the backward pass,
+    so no score tensor reaches HBM. ``interpret=True`` runs the Pallas
+    interpreter (CPU validation).
+    """
+    B, S, H, Dh = q.shape
+    Hk = k.shape[2]
+    G = H // Hk
+    bs = _splash_block(S)
+    blocks = splash.BlockSizes(
+        block_q=bs, block_kv=bs, block_kv_compute=bs,
+        block_q_dkv=bs, block_kv_dkv=bs, block_kv_dkv_compute=bs,
+        block_q_dq=bs, block_kv_dq=bs,
+    )
+    mask = splash.MultiHeadMask([splash.CausalMask((S, S))] * G)
+    kernel = splash.make_splash_mqa_single_device(
+        mask, block_sizes=blocks, interpret=interpret
+    )
+    scale = 1.0 / math.sqrt(Dh)
+    # [B, Hk, G, S, Dh] / [B, Hk, S, Dh]
+    qr = (q.reshape(B, S, Hk, G, Dh) * scale).transpose(0, 2, 3, 1, 4)
+    kr = k.transpose(0, 2, 1, 3)
+    vr = v.transpose(0, 2, 1, 3)
+    out = jax.vmap(jax.vmap(kernel))(qr, kr, vr)
+    return out.transpose(0, 3, 1, 2, 4).reshape(B, S, H, Dh)
+
+
+def _fused_applies(cfg: AttentionConfig, S: int, window: int | None) -> bool:
+    """Whether ``fused_causal_attention`` can take this self-attention call:
+    plain causal, a tileable shape, and no multi-device mesh bound, neither
+    the model's logical-axis rules nor JAX's mesh context."""
+    mesh, _ = current_rules()
+    return (
+        cfg.causal
+        and window is None
+        and cfg.logit_softcap is None
+        and _splash_block(S) is not None
+        and cfg.head_dim in (64, 128, 256)
+        and (mesh is None or mesh.size <= 1)
+        and jax.sharding.get_abstract_mesh().size <= 1   # 0 when none is bound
+    )
+
+
 def decode_attention(
     q: jax.Array,            # [B, 1, H, Dh]
     k_cache: jax.Array,      # [B, S, Hk, Dh]
@@ -228,14 +292,27 @@ def attn_apply(
         k = shard_hint(k, DP, None, None, None)
         v = shard_hint(v, DP, None, None, None)
     window = cfg.sliding_window if local else None
-    out = chunked_attention(
-        q, k, v,
-        causal=cfg.causal,
-        window=window,
-        q_chunk=cfg.q_chunk,
-        kv_chunk=cfg.kv_chunk,
-        softcap=cfg.logit_softcap,
-    )
+
+    def chunked(q, k, v):
+        with jax.named_scope("attn.chunked"):
+            return chunked_attention(
+                q, k, v,
+                causal=cfg.causal,
+                window=window,
+                q_chunk=cfg.q_chunk,
+                kv_chunk=cfg.kv_chunk,
+                softcap=cfg.logit_softcap,
+            )
+
+    def fused(q, k, v):
+        with jax.named_scope("attn.flash"):
+            return fused_causal_attention(q, k, v)
+
+    if _fused_applies(cfg, S, window):
+        # chosen when the step is lowered: the kernel on TPU, XLA elsewhere
+        out = jax.lax.platform_dependent(q, k, v, tpu=fused, default=chunked)
+    else:
+        out = chunked(q, k, v)
     return out.reshape(B, S, -1) @ p["wo"]
 
 

@@ -1,6 +1,10 @@
 """Per-kernel validation: interpret-mode Pallas vs pure-jnp oracle,
 swept over shapes/dtypes, plus hypothesis property tests on invariants."""
 
+import contextlib
+import dataclasses
+from functools import partial
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -95,6 +99,99 @@ def test_flash_attention_block_invariance(sq, hk, g, blk):
     a = flash_attention(q, k, v, block_q=blk, block_k=blk, interpret=True)
     b = flash_attention(q, k, v, block_q=16, block_k=16, interpret=True)
     np.testing.assert_allclose(np.asarray(a), np.asarray(b), rtol=3e-5, atol=3e-5)
+
+
+# ------------------------------------------------------------------ splash
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16], ids=["f32", "bf16"])
+@pytest.mark.parametrize("H,Hk", [(4, 1), (2, 2)], ids=["gqa4", "mha"])
+@pytest.mark.parametrize("Dh", [64, 128])
+def test_fused_causal_attention_matches_chunked(Dh, H, Hk, dtype):
+    """The TPU kernel path (interpreted) matches ``chunked_attention`` in
+    the output and in dq/dk/dv."""
+    from repro.models.attention import chunked_attention, fused_causal_attention
+
+    B, S = 2, 256
+    ks = jax.random.split(jax.random.PRNGKey(Dh + H), 4)
+    q = rand(ks[0], (B, S, H, Dh), dtype)
+    k = rand(ks[1], (B, S, Hk, Dh), dtype)
+    v = rand(ks[2], (B, S, Hk, Dh), dtype)
+    cot = rand(ks[3], (B, S, H, Dh), dtype)
+
+    def out_and_grads(attend):
+        out, vjp = jax.vjp(attend, q, k, v)
+        return (out, *vjp(cot))
+
+    got = out_and_grads(partial(fused_causal_attention, interpret=True))
+    want = out_and_grads(chunked_attention)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+    for name, a, b in zip(("out", "dq", "dk", "dv"), got, want):
+        np.testing.assert_allclose(
+            np.asarray(a, np.float32), np.asarray(b, np.float32),
+            rtol=tol, atol=tol, err_msg=name,
+        )
+
+
+def _granite_attention(**changes):
+    from repro.configs.base import AttentionConfig
+
+    return dataclasses.replace(
+        AttentionConfig(num_heads=32, num_kv_heads=8, head_dim=64), **changes
+    )
+
+
+def _jax_mesh():
+    return jax.sharding.use_abstract_mesh(
+        jax.sharding.AbstractMesh((2, 2), ("data", "model"))
+    )
+
+
+def _model_mesh():
+    from repro.distributed.sharding import default_rules, logical_axis_rules
+
+    mesh = jax.sharding.AbstractMesh((2, 2), ("data", "model"))
+    return logical_axis_rules(mesh, default_rules(multi_pod=False))
+
+
+SELECTION = [
+    # (case, attention config changes, local layer, S, context, kernel expected)
+    ("granite", {}, False, 256, contextlib.nullcontext, True),
+    ("softcap", {"logit_softcap": 50.0}, False, 256, contextlib.nullcontext, False),
+    ("window", {"sliding_window": 64}, True, 256, contextlib.nullcontext, False),
+    ("bidirectional", {"causal": False}, False, 256, contextlib.nullcontext, False),
+    ("ragged", {}, False, 200, contextlib.nullcontext, False),
+    ("jax_mesh", {}, False, 256, _jax_mesh, False),
+    ("model_mesh", {}, False, 256, _model_mesh, False),
+]
+
+
+@pytest.mark.parametrize(
+    "changes,local,S,context,kernel",
+    [c[1:] for c in SELECTION], ids=[c[0] for c in SELECTION],
+)
+def test_attention_selects_kernel_from_the_call(changes, local, S, context, kernel):
+    """Lowered for TPU, only a plain causal, tileable, unsharded
+    self-attention holds the splash kernel; lowered for CPU, none does."""
+    from repro.models import attention as attn
+    from repro.models.layers import Layout
+
+    cfg = _granite_attention(**changes)
+    D = 256
+    params, _ = attn.attn_init(
+        jax.random.PRNGKey(0), cfg, D, Layout(jnp.bfloat16, jnp.bfloat16), 1e-5
+    )
+    x = jax.ShapeDtypeStruct((1, S, D), jnp.bfloat16)
+
+    def loss(p, x):
+        out = attn.attn_apply(p, cfg, x, local=local, eps=1e-5)
+        return jnp.sum(out.astype(jnp.float32))
+
+    def lowered(platform):
+        with context():
+            traced = jax.jit(jax.grad(loss)).trace(params, x)
+            return traced.lower(lowering_platforms=(platform,)).as_text()
+
+    assert ("tpu_custom_call" in lowered("tpu")) == kernel
+    assert "tpu_custom_call" not in lowered("cpu")
 
 
 # ------------------------------------------------------------------ wkv6

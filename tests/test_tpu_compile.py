@@ -11,6 +11,7 @@ file.
 from __future__ import annotations
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -79,6 +80,10 @@ def test_train_step_fits_one_v5e_at_granite_width(one_chip):
         .compile()
     )
     assert _hbm_bytes(compiled) <= V5E_HBM_BYTES
+    # attention runs as the fused kernel: no f32 score tile reaches HBM
+    hlo = compiled.as_text()
+    assert "tpu_custom_call" in hlo
+    assert not re.search(r"f32\[[0-9,]*512,1024\]", hlo)
 
 
 def _flash(s):
