@@ -11,6 +11,7 @@ Sources per the assignment brief:
     qwen3-4b                  [hf:Qwen/Qwen3 family]
     whisper-base              [arXiv:2212.04356]
     jamba-v0.1-52b            [arXiv:2403.19887]
+    granite-4.0-h-micro       [hf:ibm-granite/granite-4.0-h-micro]
 
 ``reduced(cfg)`` shrinks any config to smoke-test size while preserving
 its family structure (pattern, MoE, SSM, enc-dec wiring).
@@ -23,6 +24,7 @@ import dataclasses
 from repro.configs.base import (
     AttentionConfig,
     EncDecConfig,
+    Mamba2Config,
     ModelConfig,
     MoEConfig,
     RWKVConfig,
@@ -220,6 +222,32 @@ def jamba_52b() -> ModelConfig:
     )
 
 
+@register("granite-4.0-h-micro")
+def granite4_h_micro() -> ModelConfig:
+    """Granite 4.0-H Micro: period-10 block of 9 Mamba-2 mixers and one
+    GQA attention mixer with no positional embedding (layer i is attention
+    when i % 10 == 5), a SwiGLU MLP after every mixer, muP multipliers,
+    tied embeddings."""
+    return ModelConfig(
+        name="granite-4.0-h-micro",
+        family="hybrid",
+        n_layers=40,
+        d_model=2048,
+        d_ff=8192,
+        vocab_size=100352,
+        pattern=("mamba2:mlp",) * 5 + ("attn:mlp",) + ("mamba2:mlp",) * 4,
+        attention=AttentionConfig(num_heads=32, num_kv_heads=8, head_dim=64,
+                                  scale=0.015625, rope=False),
+        mamba2=Mamba2Config(n_heads=64, head_dim=64, d_state=128, n_groups=1,
+                            d_conv=4, expand=2, chunk=256),
+        tie_embeddings=True,
+        embedding_multiplier=12.0,
+        residual_multiplier=0.22,
+        logits_scaling=8.0,
+        supports_long_context=True,   # only 4 of 40 layers hold KV
+    )
+
+
 # ------------------------------------------------------------------ reduced
 def reduced(cfg: ModelConfig) -> ModelConfig:
     """Shrink to smoke-test size, preserving the family structure."""
@@ -250,6 +278,12 @@ def reduced(cfg: ModelConfig) -> ModelConfig:
         )
     if cfg.ssm is not None:
         kw["ssm"] = dataclasses.replace(cfg.ssm, d_state=8, dt_rank=8, chunk=16)
+    if cfg.mamba2 is not None:
+        # d_inner = n_heads * head_dim = expand * d_model, as published
+        kw["mamba2"] = dataclasses.replace(
+            cfg.mamba2, n_heads=8, head_dim=cfg.mamba2.expand * kw["d_model"] // 8,
+            d_state=32, chunk=64,
+        )
     if cfg.rwkv is not None:
         kw["rwkv"] = dataclasses.replace(
             cfg.rwkv, head_size=16, decay_lora=8, gate_lora=8,

@@ -6,7 +6,7 @@ A config describes the layer stack as a repeating *pattern* of sublayer
 kinds (period P); ``n_layers = n_periods * P + len(remainder)``. Pattern
 entries are "<mixer>:<ffn>" strings:
 
-    mixer ∈ {attn, local, mamba, rwkv}     ffn ∈ {mlp, moe, rwkv}
+    mixer ∈ {attn, local, mamba, mamba2, rwkv}     ffn ∈ {mlp, moe, rwkv}
 
 e.g. gemma3 = ("local:mlp",)*5 + ("attn:mlp",)  — 5 sliding-window layers
 per global layer; jamba period-8 interleaves 7 mamba + 1 attention with
@@ -34,6 +34,8 @@ class AttentionConfig:
     causal: bool = True
     logit_softcap: float | None = None
     kv_replicate_hint: bool = True      # False: let SPMD keep K/V sharded
+    scale: float | None = None          # score scale; None -> 1/sqrt(head_dim)
+    rope: bool = True                   # False: no positional embedding (NoPE)
 
 
 @dataclass(frozen=True)
@@ -56,6 +58,36 @@ class SSMConfig:
     dt_rank: int = 0                    # 0 -> ceil(d_model / 16)
     chunk: int = 256                    # time-chunking for the scan
     scan_dtype: str = "float32"         # bf16 halves the chunk temporaries
+
+
+@dataclass(frozen=True)
+class Mamba2Config:
+    """Mamba-2 mixer (SSD, arXiv:2405.21060): ``n_heads`` heads of
+    ``head_dim`` channels share a scalar decay each; B and C are shared by
+    the heads of each of ``n_groups`` groups."""
+
+    n_heads: int
+    head_dim: int
+    d_state: int
+    n_groups: int = 1
+    d_conv: int = 4
+    expand: int = 2
+    chunk: int = 256                    # SSD chunk length
+
+    @property
+    def d_inner(self) -> int:
+        return self.n_heads * self.head_dim
+
+    @property
+    def conv_dim(self) -> int:
+        """Channels of the causal conv: x, B and C."""
+        return self.d_inner + 2 * self.n_groups * self.d_state
+
+    def param_count(self, d_model: int) -> int:
+        in_proj = d_model * (self.d_inner + self.conv_dim + self.n_heads)  # z | xBC | dt
+        conv = self.d_conv * self.conv_dim + self.conv_dim
+        per_head = 3 * self.n_heads                                       # dt_bias, A_log, D
+        return in_proj + conv + per_head + self.d_inner + self.d_inner * d_model
 
 
 @dataclass(frozen=True)
@@ -87,6 +119,7 @@ class ModelConfig:
     attention: AttentionConfig | None = None
     moe: MoEConfig | None = None
     ssm: SSMConfig | None = None
+    mamba2: Mamba2Config | None = None
     rwkv: RWKVConfig | None = None
     encdec: EncDecConfig | None = None
     frontend: str = "none"               # none | vision_stub | audio_stub
@@ -105,6 +138,11 @@ class ModelConfig:
     # every layer (scan bodies are otherwise counted once — see
     # EXPERIMENTS.md §Roofline methodology)
     unroll_stack: bool = False
+    # muP multipliers (granite 4.0); None keeps the plain transformer:
+    # embeddings times sqrt(d_model), residual branches and logits unscaled
+    embedding_multiplier: float | None = None
+    residual_multiplier: float | None = None
+    logits_scaling: float | None = None     # logits are divided by this
 
     # ---------------------------------------------------------------- sizes
     @property
@@ -135,7 +173,7 @@ class ModelConfig:
         total = V * D  # embeddings
         if not self.tie_embeddings:
             total += V * D
-        counts = {k: 0 for k in ("attn", "local", "attnx", "mamba", "rwkv")}
+        counts = {k: 0 for k in ("attn", "local", "attnx", "mamba", "mamba2", "rwkv")}
         ffns = {k: 0 for k in ("mlp", "moe", "rwkv")}
         full = list(self.pattern) * self.n_periods + list(self.remainder)
         for entry in full:
@@ -161,6 +199,8 @@ class ModelConfig:
                 + d_in * s.d_state + d_in + d_in * D
             )
             total += counts["mamba"] * mamba_p
+        if self.mamba2 is not None:
+            total += counts["mamba2"] * self.mamba2.param_count(D)
         if self.rwkv is not None:
             total += counts["rwkv"] * (4 * D * D + D * D)  # r,k,v,g,o proj
             total += counts["rwkv"] * (

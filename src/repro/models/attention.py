@@ -66,12 +66,14 @@ def chunked_attention(
     kv_chunk: int = 1024,
     softcap: float | None = None,
     q_offset: int = 0,       # absolute position of q[0] (prefill continuation)
+    scale: float | None = None,   # None: 1/sqrt(Dh)
 ) -> jax.Array:
     """Flash-style attention with O(S·chunk) live memory."""
     B, Sq, H, Dh = q.shape
     _, Sk, Hk, _ = k.shape
     G = H // Hk
-    scale = 1.0 / math.sqrt(Dh)
+    if scale is None:
+        scale = 1.0 / math.sqrt(Dh)
     cdt = q.dtype
 
     q_chunk = min(q_chunk, Sq)
@@ -148,6 +150,7 @@ def fused_causal_attention(
     k: jax.Array,            # [B, S, Hk, Dh]
     v: jax.Array,            # [B, S, Hk, Dh]
     *,
+    scale: float | None = None,   # None: 1/sqrt(Dh)
     interpret: bool = False,
 ) -> jax.Array:
     """Causal self-attention as JAX's Pallas splash kernel (TPU).
@@ -171,7 +174,8 @@ def fused_causal_attention(
     kernel = splash.make_splash_mqa_single_device(
         mask, block_sizes=blocks, interpret=interpret
     )
-    scale = 1.0 / math.sqrt(Dh)
+    if scale is None:
+        scale = 1.0 / math.sqrt(Dh)
     # [B, Hk, G, S, Dh] / [B, Hk, S, Dh]
     qr = (q.reshape(B, S, Hk, G, Dh) * scale).transpose(0, 2, 3, 1, 4)
     kr = k.transpose(0, 2, 1, 3)
@@ -204,6 +208,7 @@ def decode_attention(
     *,
     window: int | None = None,
     softcap: float | None = None,
+    scale: float | None = None,   # None: 1/sqrt(Dh)
 ) -> jax.Array:
     """Single-token attention against a cache — one matmul pass, fp32
     softmax. Memory-bound by the cache read (the roofline term that
@@ -211,7 +216,8 @@ def decode_attention(
     B, _, H, Dh = q.shape
     _, S, Hk, _ = k_cache.shape
     G = H // Hk
-    scale = 1.0 / math.sqrt(Dh)
+    if scale is None:
+        scale = 1.0 / math.sqrt(Dh)
     qg = q.reshape(B, Hk, G, Dh) * scale
     s = jnp.einsum(
         "bhgd,bshd->bhgs", qg, k_cache, preferred_element_type=jnp.float32
@@ -267,8 +273,9 @@ def _project_qkv(p, cfg: AttentionConfig, x, positions, theta, eps):
     if cfg.qk_norm:
         q = qk_head_norm(q, p["q_norm"], eps)
         k = qk_head_norm(k, p["k_norm"], eps)
-    q = apply_rope(q, positions, theta)
-    k = apply_rope(k, positions, theta)
+    if cfg.rope:
+        q = apply_rope(q, positions, theta)
+        k = apply_rope(k, positions, theta)
     return q, k, v
 
 
@@ -302,11 +309,12 @@ def attn_apply(
                 q_chunk=cfg.q_chunk,
                 kv_chunk=cfg.kv_chunk,
                 softcap=cfg.logit_softcap,
+                scale=cfg.scale,
             )
 
     def fused(q, k, v):
         with jax.named_scope("attn.flash"):
-            return fused_causal_attention(q, k, v)
+            return fused_causal_attention(q, k, v, scale=cfg.scale)
 
     if _fused_applies(cfg, S, window):
         # chosen when the step is lowered: the kernel on TPU, XLA elsewhere
@@ -347,10 +355,10 @@ def attn_decode(
         # ring buffer: every live slot is within the window by construction
         mask_len = jnp.minimum(length + 1, S_cache)
         out = decode_attention(q, cache_k, cache_v, mask_len, window=None,
-                               softcap=cfg.logit_softcap)
+                               softcap=cfg.logit_softcap, scale=cfg.scale)
     else:
         out = decode_attention(q, cache_k, cache_v, length + 1, window=None,
-                               softcap=cfg.logit_softcap)
+                               softcap=cfg.logit_softcap, scale=cfg.scale)
     return out.reshape(B, 1, -1) @ p["wo"], cache_k, cache_v
 
 

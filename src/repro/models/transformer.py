@@ -4,8 +4,8 @@ A config's layer stack is ``pattern * n_periods + remainder``. All periods
 share one traced body (compile time stays flat in depth); parameters are
 stacked with a leading ``n_periods`` dim. Sublayer kinds:
 
-    mixer: attn (global), local (sliding window), mamba, rwkv, attnx
-           (self+cross, whisper decoder)
+    mixer: attn (global), local (sliding window), mamba, mamba2, rwkv,
+           attnx (self+cross, whisper decoder)
     ffn:   mlp (SwiGLU), moe, rwkv (channel-mix)
 
 Three entry points per model: ``apply`` (train/prefill logits),
@@ -27,6 +27,7 @@ import jax.numpy as jnp
 from repro.configs.base import ModelConfig
 from repro.distributed.sharding import DP, FSDP, TP, shard_hint
 from repro.models import attention as attn
+from repro.models import mamba2 as mamba2_mod
 from repro.models import moe as moe_mod
 from repro.models import rwkv as rwkv_mod
 from repro.models import ssm as ssm_mod
@@ -60,6 +61,8 @@ def _entry_init(key, entry: str, cfg: ModelConfig, layout: Layout):
             )
     elif mixer == "mamba":
         p["mixer"], s["mixer"] = ssm_mod.ssm_init(kmix, cfg.ssm, cfg.d_model, layout)
+    elif mixer == "mamba2":
+        p["mixer"], s["mixer"] = mamba2_mod.mamba2_init(kmix, cfg.mamba2, cfg.d_model, layout)
     elif mixer == "rwkv":
         p["mixer"], s["mixer"] = rwkv_mod.rwkv_block_init(
             kmix, cfg.rwkv, cfg.d_model, layout
@@ -79,6 +82,13 @@ def _entry_init(key, entry: str, cfg: ModelConfig, layout: Layout):
     return p, s
 
 
+def _residual(x, h, cfg: ModelConfig):
+    """x + h, the branch scaled by the config's residual multiplier if set."""
+    if cfg.residual_multiplier is not None:
+        h = h * cfg.residual_multiplier
+    return x + h
+
+
 def _entry_apply(p, entry: str, cfg: ModelConfig, x, ctx) -> tuple[jax.Array, jax.Array]:
     """Pre-LN residual block. Returns (x, aux_loss)."""
     mixer, ffn = entry.split(":")
@@ -95,14 +105,16 @@ def _entry_apply(p, entry: str, cfg: ModelConfig, x, ctx) -> tuple[jax.Array, ja
             p["mixer"], cfg.attention, h, local=False, eps=cfg.norm_eps,
             positions=ctx.get("positions"),
         )
-        x = x + h
+        x = _residual(x, h, cfg)
         hx = rms_norm(x, p["xnorm"], cfg.norm_eps)
         h = _cross_attn_apply(p["xattn"], cfg, hx, ctx["encoder_out"])
     elif mixer == "mamba":
         h = ssm_mod.ssm_apply(p["mixer"], cfg.ssm, h)
+    elif mixer == "mamba2":
+        h = mamba2_mod.mamba2_apply(p["mixer"], cfg.mamba2, h, eps=cfg.norm_eps)
     elif mixer == "rwkv":
         h = rwkv_mod.rwkv_block_apply(p["mixer"], cfg.rwkv, h)
-    x = x + h
+    x = _residual(x, h, cfg)
     x = shard_hint(x, DP, None, None)
     h = rms_norm(x, p["norm2"], cfg.norm_eps)
     if ffn == "mlp":
@@ -111,7 +123,7 @@ def _entry_apply(p, entry: str, cfg: ModelConfig, x, ctx) -> tuple[jax.Array, ja
         h, aux = moe_mod.moe_apply(p["ffn"], cfg.moe, h, cfg.act)
     elif ffn == "rwkv":
         h = rwkv_mod.rwkv_ffn_apply(p["ffn"], h)
-    x = x + h
+    x = _residual(x, h, cfg)
     return shard_hint(x, DP, None, None), aux
 
 
@@ -151,6 +163,10 @@ def _entry_cache_init(entry: str, cfg: ModelConfig, batch: int, cache_len: int,
         d_in = s.expand * cfg.d_model
         c["conv"] = jnp.zeros((batch, s.d_conv - 1, d_in), dtype)
         c["h"] = jnp.zeros((batch, d_in, s.d_state), jnp.float32)
+    elif mixer == "mamba2":
+        m = cfg.mamba2
+        c["conv"] = jnp.zeros((batch, m.d_conv - 1, m.conv_dim), dtype)
+        c["h"] = jnp.zeros((batch, m.n_heads, m.head_dim, m.d_state), jnp.float32)
     elif mixer == "rwkv":
         r = cfg.rwkv
         H = cfg.d_model // r.head_size
@@ -173,7 +189,7 @@ def _entry_decode(p, entry: str, cfg: ModelConfig, x, cache, lengths, ctx):
         )
         new_cache["k"], new_cache["v"] = nk, nv
         if mixer == "attnx":
-            x = x + h
+            x = _residual(x, h, cfg)
             hx = rms_norm(x, p["xnorm"], cfg.norm_eps)
             h = _cross_decode(p["xattn"], cfg, hx, cache["xk"], cache["xv"])
     elif mixer == "mamba":
@@ -181,12 +197,17 @@ def _entry_decode(p, entry: str, cfg: ModelConfig, x, cache, lengths, ctx):
             p["mixer"], cfg.ssm, h, (cache["conv"], cache["h"])
         )
         new_cache["conv"], new_cache["h"] = nc, nh
+    elif mixer == "mamba2":
+        h, (nc, nh) = mamba2_mod.mamba2_decode(
+            p["mixer"], cfg.mamba2, h, (cache["conv"], cache["h"]), eps=cfg.norm_eps
+        )
+        new_cache["conv"], new_cache["h"] = nc, nh
     elif mixer == "rwkv":
         h, (nx, nS) = rwkv_mod.rwkv_block_decode(
             p["mixer"], cfg.rwkv, h, (cache["x_tm"], cache["S"])
         )
         new_cache["x_tm"], new_cache["S"] = nx, nS
-    x = x + h
+    x = _residual(x, h, cfg)
     h = rms_norm(x, p["norm2"], cfg.norm_eps)
     if ffn == "mlp":
         h = mlp_apply(p["ffn"], h, cfg.act)
@@ -195,7 +216,7 @@ def _entry_decode(p, entry: str, cfg: ModelConfig, x, cache, lengths, ctx):
     elif ffn == "rwkv":
         h, nx = rwkv_mod.rwkv_ffn_decode(p["ffn"], h, cache["x_cm"])
         new_cache["x_cm"] = nx
-    return x + h, new_cache
+    return _residual(x, h, cfg), new_cache
 
 
 def _entry_prefill(p, entry: str, cfg: ModelConfig, x, cache_len: int, ctx):
@@ -215,6 +236,7 @@ def _entry_prefill(p, entry: str, cfg: ModelConfig, x, cache_len: int, ctx):
         o = attn.chunked_attention(
             q, k, v, causal=a.causal, window=window,
             q_chunk=a.q_chunk, kv_chunk=a.kv_chunk, softcap=a.logit_softcap,
+            scale=a.scale,
         )
         h = o.reshape(B, S, -1) @ p["mixer"]["wo"]
         # build the cache
@@ -232,7 +254,7 @@ def _entry_prefill(p, entry: str, cfg: ModelConfig, x, cache_len: int, ctx):
             c["k"] = jax.lax.dynamic_update_slice_in_dim(ck, k, 0, axis=1)
             c["v"] = jax.lax.dynamic_update_slice_in_dim(cv, v, 0, axis=1)
         if mixer == "attnx":
-            x = x + h
+            x = _residual(x, h, cfg)
             hx = rms_norm(x, p["xnorm"], cfg.norm_eps)
             enc = ctx["encoder_out"]
             h = _cross_attn_apply(p["xattn"], cfg, hx, enc)
@@ -246,10 +268,15 @@ def _entry_prefill(p, entry: str, cfg: ModelConfig, x, cache_len: int, ctx):
     elif mixer == "mamba":
         h, (conv, hs) = ssm_mod.ssm_apply(p["mixer"], cfg.ssm, h, return_state=True)
         c["conv"], c["h"] = conv, hs
+    elif mixer == "mamba2":
+        h, (conv, hs) = mamba2_mod.mamba2_apply(
+            p["mixer"], cfg.mamba2, h, eps=cfg.norm_eps, return_state=True
+        )
+        c["conv"], c["h"] = conv, hs
     elif mixer == "rwkv":
         h, (x_tm, S_fin) = rwkv_mod.rwkv_block_prefill(p["mixer"], cfg.rwkv, h)
         c["x_tm"], c["S"] = x_tm, S_fin
-    x = x + h
+    x = _residual(x, h, cfg)
     h2 = rms_norm(x, p["norm2"], cfg.norm_eps)
     if ffn == "mlp":
         h2o = mlp_apply(p["ffn"], h2, cfg.act)
@@ -258,7 +285,7 @@ def _entry_prefill(p, entry: str, cfg: ModelConfig, x, cache_len: int, ctx):
     elif ffn == "rwkv":
         h2o = rwkv_mod.rwkv_ffn_apply(p["ffn"], h2)
         c["x_cm"] = h2[:, -1, :]
-    return x + h2o, c
+    return _residual(x, h2o, cfg), c
 
 
 def _cross_decode(p, cfg: ModelConfig, x, xk, xv):
@@ -342,7 +369,8 @@ class LM:
     def embed_tokens(p, cfg: ModelConfig, tokens, embeds=None):
         layout = Layout.from_config(cfg)
         x = jnp.take(p["embed"], tokens, axis=0).astype(layout.compute_dtype)
-        x = x * math.sqrt(cfg.d_model)
+        mult = cfg.embedding_multiplier
+        x = x * (math.sqrt(cfg.d_model) if mult is None else mult)
         if embeds is not None:
             x = jnp.concatenate([embeds.astype(layout.compute_dtype), x], axis=1)
         return shard_hint(x, DP, None, None)
@@ -384,23 +412,32 @@ class LM:
         ctx = {"positions": positions, "encoder_out": encoder_out}
         aux_total = jnp.zeros((), jnp.float32)
         if cfg.n_periods > 0:
+            # a period of several sublayers rematerialises each one, so the
+            # backward pass holds one sublayer's activations at a time
+            remat_each = len(cfg.pattern) > 1
+
+            def sublayer(entry):
+                def fn(params, xc):
+                    return _entry_apply(params, entry, cfg, xc, ctx)
+                return _remat_wrap(fn, cfg) if remat_each else fn
+
+            sublayers = [sublayer(entry) for entry in cfg.pattern]
+
             def period_body(carry, params):
                 xc, aux = carry
-                for pos, entry in enumerate(cfg.pattern):
-                    xc, a = _entry_apply(params[f"pat{pos}"], entry, cfg, xc, ctx)
+                for pos, fn in enumerate(sublayers):
+                    xc, a = fn(params[f"pat{pos}"], xc)
                     aux = aux + a
                 return (xc, aux), None
 
+            body = period_body if remat_each else _remat_wrap(period_body, cfg)
             if cfg.unroll_stack:
-                body = _remat_wrap(period_body, cfg)
                 for i in range(cfg.n_periods):
                     (x, aux_total), _ = body(
                         (x, aux_total), _tree_index(p["stack"], i)
                     )
             else:
-                (x, aux_total), _ = jax.lax.scan(
-                    _remat_wrap(period_body, cfg), (x, aux_total), p["stack"]
-                )
+                (x, aux_total), _ = jax.lax.scan(body, (x, aux_total), p["stack"])
         for i, entry in enumerate(cfg.remainder):
             x, a = _entry_apply(p[f"rem{i}"], entry, cfg, x, ctx)
             aux_total = aux_total + a
@@ -447,21 +484,25 @@ class LM:
 
         def chunk_body(acc, inp):
             h, lab, mk = inp
-            logits = unembed_logits(h, table) + vmask
+            logits = _scaled_logits(h, table, cfg) + vmask
             logz = jax.nn.logsumexp(logits, axis=-1)
             gold = jnp.take_along_axis(logits, lab[..., None], axis=-1)[..., 0]
             nll = (logz - gold) * mk
             return (acc[0] + jnp.sum(nll), acc[1] + jnp.sum(mk)), None
 
+        # the backward pass needs each chunk's fp32 softmax; past
+        # _LOSS_SAVE_BYTES for the whole batch, recompute it chunk by chunk
+        saved = B * nchunk * seq_chunk * cfg.vocab_padded * 4
+        body = jax.checkpoint(chunk_body) if saved > _LOSS_SAVE_BYTES else chunk_body
         (tot, cnt), _ = jax.lax.scan(
-            chunk_body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
+            body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
             (hs, ls, ms),
         )
         return tot / jnp.maximum(cnt, 1.0)
 
     @staticmethod
     def logits(p, cfg: ModelConfig, hidden):
-        return unembed_logits(hidden, LM.unembed_table(p, cfg)) + _vocab_pad_mask(cfg)
+        return _scaled_logits(hidden, LM.unembed_table(p, cfg), cfg) + _vocab_pad_mask(cfg)
 
     # ---------------------------------------------------------- caches
     @staticmethod
@@ -571,6 +612,19 @@ class LM:
 
 
 # ------------------------------------------------------------------ misc
+# bytes of fp32 softmax that LM.loss keeps for the backward pass before it
+# recomputes each chunk's logits instead
+_LOSS_SAVE_BYTES = 2 << 30
+
+
+def _scaled_logits(h, table, cfg: ModelConfig):
+    """fp32 logits, divided by the config's logits scaling if set."""
+    logits = unembed_logits(h, table)
+    if cfg.logits_scaling is not None:
+        logits = logits / cfg.logits_scaling
+    return logits
+
+
 def _tree_index(tree, i: int):
     return jax.tree.map(lambda a: a[i], tree)
 
