@@ -51,6 +51,13 @@ def current_rules():
     return stack[-1] if stack else (None, {})
 
 
+def unsharded() -> bool:
+    """Whether no multi-device mesh is bound, neither the model's
+    logical-axis rules nor JAX's mesh context."""
+    mesh, _ = current_rules()
+    return (mesh is None or mesh.size <= 1) and jax.sharding.get_abstract_mesh().size <= 1
+
+
 def default_rules(multi_pod: bool) -> dict:
     if multi_pod:
         return {DP: ("pod", "data"), TP: "model", FSDP: "data", SP: "data", EP: "model"}

@@ -22,7 +22,7 @@ import jax.numpy as jnp
 from jax.experimental.pallas.ops.tpu import splash_attention as splash
 
 from repro.configs.base import AttentionConfig
-from repro.distributed.sharding import DP, FSDP, TP, current_rules, shard_hint
+from repro.distributed.sharding import DP, FSDP, TP, shard_hint, unsharded
 from repro.models.layers import (
     Layout,
     apply_rope,
@@ -188,15 +188,13 @@ def _fused_applies(cfg: AttentionConfig, S: int, window: int | None) -> bool:
     """Whether ``fused_causal_attention`` can take this self-attention call:
     plain causal, a tileable shape, and no multi-device mesh bound, neither
     the model's logical-axis rules nor JAX's mesh context."""
-    mesh, _ = current_rules()
     return (
         cfg.causal
         and window is None
         and cfg.logit_softcap is None
         and _splash_block(S) is not None
         and cfg.head_dim in (64, 128, 256)
-        and (mesh is None or mesh.size <= 1)
-        and jax.sharding.get_abstract_mesh().size <= 1   # 0 when none is bound
+        and unsharded()
     )
 
 

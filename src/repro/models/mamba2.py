@@ -8,8 +8,10 @@
 
 Training and prefill run the chunked SSD form (``ssd``): inside each
 chunk the masked ``(L ∘ C Bᵀ)(Δ x)`` matmuls, across chunks a scan that
-carries the state. Decay math (Δ, the cumulative sums of Δ A, their
-exponentials) and the carried state are float32; matmul operands take
+carries the state. On one TPU the in-chunk term runs as the Pallas
+kernel pair of ``kernels/ssd``, its decay masks kept in VMEM; elsewhere
+as ``_intra_chunk`` in XLA. Decay math (Δ, the cumulative sums of Δ A,
+their exponentials) and the carried state are float32; matmul operands take
 the dtype of the mixer's input, accumulating in float32. Decode is the
 one-step recurrence on a conv state [B, K-1, conv_dim] and an SSM state
 [B, H, P, N] in float32.
@@ -23,7 +25,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.configs.base import Mamba2Config
-from repro.distributed.sharding import FSDP
+from repro.distributed.sharding import FSDP, unsharded
+from repro.kernels.ssd import ops as ssd_kernel
 from repro.models.layers import Layout, dense_init
 
 F32 = jnp.float32
@@ -114,6 +117,42 @@ def _state_pass(states, chunk_decay):
     return jnp.swapaxes(h_in, 0, 1), h_fin
 
 
+def _intra_chunk(C, B, cs, x, dt):
+    """Inside each chunk: y_l = sum_{s<=l} (C_l . B_s) exp(cs_l - cs_s) Δ_s x_s.
+    C, B [b, c, L, G, N] and x [b, c, L, G, J, P] in the matmul dtype;
+    Δ and cs [b, c, L, G, J], cs the cumulative sums of Δ A. Returns y in
+    cs's dtype, the masked product's operands cast to x's."""
+    L = C.shape[2]
+    ft, mt = cs.dtype, x.dtype
+    xdt = (x.astype(ft) * dt[..., None]).astype(mt)
+    cb = jnp.einsum("bclgn,bcsgn->bcgls", C, B, preferred_element_type=ft)
+    cst = jnp.moveaxis(cs, 2, -1)                       # [b, c, g, j, l]
+    seg = cst[..., :, None] - cst[..., None, :]
+    causal = jnp.tril(jnp.ones((L, L), bool))
+    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))   # [b, c, g, j, l, s]
+    m = (cb[:, :, :, None] * decay).astype(mt)
+    return jnp.einsum("bcgjls,bcsgjp->bclgjp", m, xdt, preferred_element_type=ft)
+
+
+def _intra_chunk_dispatch(C, B, cs, x, dt):
+    """``_intra_chunk`` as the Pallas kernel pair (``kernels/ssd``) when the
+    step is lowered for TPU and the shape tiles with no multi-device mesh
+    bound; the XLA form everywhere else."""
+    def xla(*args):
+        with jax.named_scope("mamba2.ssd.xla"):
+            return _intra_chunk(*args)
+
+    def kernel(*args):
+        with jax.named_scope("mamba2.ssd.kernel"):
+            return ssd_kernel.ssd_intra(*args)
+
+    _, _, L, G, N = C.shape
+    J, P = x.shape[-2:]
+    if cs.dtype == F32 and ssd_kernel.supported(L, G * J, P, N, G) and unsharded():
+        return jax.lax.platform_dependent(C, B, cs, x, dt, tpu=kernel, default=xla)
+    return xla(C, B, cs, x, dt)
+
+
 def ssd(x, dt, A, B, C, chunk: int):
     """The SSD scan in chunks of ``chunk`` steps.
 
@@ -137,16 +176,8 @@ def ssd(x, dt, A, B, C, chunk: int):
     B = B.reshape(b, nc, L, G, N)
     C = C.reshape(b, nc, L, G, N)
     cs = jnp.cumsum(dt * A.reshape(G, J), axis=2)       # [b, c, l, g, j]
-    xdt = (x.astype(ft) * dt[..., None]).astype(mt)
 
-    # inside each chunk: y_l = sum_{s<=l} (C_l . B_s) exp(cs_l - cs_s) Δ_s x_s
-    cb = jnp.einsum("bclgn,bcsgn->bcgls", C, B, preferred_element_type=ft)
-    cst = jnp.moveaxis(cs, 2, -1)                       # [b, c, g, j, l]
-    seg = cst[..., :, None] - cst[..., None, :]
-    causal = jnp.tril(jnp.ones((L, L), bool))
-    decay = jnp.exp(jnp.where(causal, seg, -jnp.inf))   # [b, c, g, j, l, s]
-    m = (cb[:, :, :, None] * decay).astype(mt)
-    y = jnp.einsum("bcgjls,bcsgjp->bclgjp", m, xdt, preferred_element_type=ft)
+    y = _intra_chunk_dispatch(C, B, cs, x, dt)
 
     # each chunk's own contribution to the state at its end, then the pass
     to_end = jnp.exp(cs[:, :, -1:] - cs) * dt           # [b, c, l, g, j]

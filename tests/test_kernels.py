@@ -1,6 +1,7 @@
 """Per-kernel validation: interpret-mode Pallas vs pure-jnp oracle,
 swept over shapes/dtypes, plus hypothesis property tests on invariants."""
 
+import collections
 import contextlib
 import dataclasses
 from functools import partial
@@ -16,6 +17,7 @@ from repro.kernels.flash_attention.ops import flash_attention
 from repro.kernels.flash_attention.ref import attention_ref
 from repro.kernels.rmsnorm.ops import rmsnorm
 from repro.kernels.rmsnorm.ref import rmsnorm_ref
+from repro.kernels.ssd.ops import ssd_intra
 from repro.kernels.ssm_scan.ops import ssm_scan
 from repro.kernels.ssm_scan.ref import ssm_scan_ref
 from repro.kernels.wkv6.ops import wkv6
@@ -192,6 +194,141 @@ def test_attention_selects_kernel_from_the_call(changes, local, S, context, kern
 
     assert ("tpu_custom_call" in lowered("tpu")) == kernel
     assert "tpu_custom_call" not in lowered("cpu")
+
+
+# ------------------------------------------------------------------ ssd
+SSD_CASES = [
+    # (case, chunk, T, heads, groups, head_dim, state, dtype, Δ·A scale, through ssd)
+    ("c128_g1_f32", 128, 256, 8, 1, 64, 128, jnp.float32, 1.0, False),
+    ("c256_g1_bf16", 256, 256, 8, 1, 64, 128, jnp.bfloat16, 1.0, False),
+    ("c256_g2_f32", 256, 512, 16, 2, 64, 128, jnp.float32, 1.0, False),
+    ("c128_g2_bf16", 128, 256, 16, 2, 64, 128, jnp.bfloat16, 1.0, False),
+    ("c128_p128_n256_f32", 128, 256, 8, 1, 128, 256, jnp.float32, 1.0, False),
+    ("underflow_f32", 256, 256, 8, 1, 64, 128, jnp.float32, 300.0, False),
+    ("ragged_T_ssd_bf16", 128, 300, 8, 1, 64, 128, jnp.bfloat16, 1.0, True),
+]
+
+
+@pytest.mark.parametrize(
+    "chunk,T,H,G,P,N,dtype,scale,through_ssd",
+    [c[1:] for c in SSD_CASES], ids=[c[0] for c in SSD_CASES],
+)
+def test_ssd_intra_kernel_matches_xla(monkeypatch, chunk, T, H, G, P, N, dtype, scale,
+                                      through_ssd):
+    """The SSD's intra-chunk kernel pair (interpreted) matches the XLA form
+    ``_intra_chunk`` in y and in dC, dB, dcs, dx and dΔ; through ``ssd``,
+    in y, the final state and the gradients of x, Δ, B and C. ``scale``
+    multiplies Δ·A: at 300 most of each chunk's mask underflows to 0."""
+    from repro.models import mamba2
+
+    b, J = 2, H // G
+    ks = jax.random.split(jax.random.PRNGKey(chunk + T + H), 7)
+    x = rand(ks[0], (b, T, H, P), dtype)
+    dt = jnp.exp(jax.random.uniform(ks[1], (b, T, H), minval=-7.0, maxval=-2.3))
+    A = -jax.random.uniform(ks[2], (H,), minval=1.0, maxval=16.0) * scale
+    Bm = rand(ks[3], (b, T, G, N), dtype)
+    Cm = rand(ks[4], (b, T, G, N), dtype)
+    tol = 2e-2 if dtype == jnp.bfloat16 else 2e-5
+
+    if through_ssd:
+        def run(intra):
+            monkeypatch.setattr(mamba2, "_intra_chunk_dispatch", intra)
+            (y, h), vjp = jax.vjp(lambda *a: mamba2.ssd(a[0], a[1], A, a[2], a[3], chunk),
+                                  x, dt, Bm, Cm)
+            cot = (rand(ks[5], y.shape), rand(ks[6], h.shape))
+            return dict(zip(("y", "h", "dx", "ddt", "dB", "dC"), (y, h, *vjp(cot))))
+
+        got = run(lambda *a: ssd_intra(*a, True))
+        want = run(mamba2._intra_chunk)
+    else:
+        nc = T // chunk
+        cs = jnp.cumsum((dt * A).reshape(b, nc, chunk, G, J), axis=2)
+        args = (Cm.reshape(b, nc, chunk, G, N), Bm.reshape(b, nc, chunk, G, N), cs,
+                x.reshape(b, nc, chunk, G, J, P), dt.reshape(b, nc, chunk, G, J))
+        dy = rand(ks[5], (b, nc, chunk, G, J, P))
+
+        def run(intra):
+            y, vjp = jax.vjp(intra, *args)
+            return dict(zip(("y", "dC", "dB", "dcs", "dx", "ddt"), (y, *vjp(dy))))
+
+        got = run(lambda *a: ssd_intra(*a, True))
+        want = run(mamba2._intra_chunk)
+    for name, w in want.items():
+        g = np.asarray(got[name], np.float32)
+        w = np.asarray(w, np.float32)
+        assert g.shape == w.shape and np.isfinite(g).all(), name
+        np.testing.assert_allclose(g, w, rtol=0, atol=tol * float(np.max(np.abs(w))),
+                                   err_msg=name)
+
+
+SSD_SELECTION = [
+    # (case, chunk, head_dim, context, kernel expected on TPU)
+    ("granite", 256, 64, contextlib.nullcontext, True),
+    ("chunk_64", 64, 64, contextlib.nullcontext, False),
+    ("head_dim_32", 256, 32, contextlib.nullcontext, False),
+    ("jax_mesh", 256, 64, lambda: _jax_mesh(), False),
+    ("model_mesh", 256, 64, lambda: _model_mesh(), False),
+]
+
+
+@pytest.mark.parametrize("chunk,P,context,kernel", [c[1:] for c in SSD_SELECTION],
+                         ids=[c[0] for c in SSD_SELECTION])
+def test_ssd_selects_kernel_from_the_shape(chunk, P, context, kernel):
+    """Lowered for TPU, only a tileable chunk and head size with no mesh
+    bound hold the SSD kernels; lowered for CPU, none does, and ``ssd``
+    equals itself run on the XLA form."""
+    from repro.models import mamba2
+
+    b, T, H, N = 1, 512, 8, 128
+    ks = jax.random.split(jax.random.PRNGKey(0), 5)
+    x = rand(ks[0], (b, T, H, P), jnp.bfloat16)
+    dt = jax.nn.softplus(rand(ks[1], (b, T, H)) - 4.0)
+    A = -jnp.exp(rand(ks[2], (H,)))
+    Bm = rand(ks[3], (b, T, 1, N), jnp.bfloat16)
+    Cm = rand(ks[4], (b, T, 1, N), jnp.bfloat16)
+
+    def loss(x, dt, Bm, Cm):
+        y, h = mamba2.ssd(x, dt, A, Bm, Cm, chunk)
+        return jnp.sum(y) + jnp.sum(h)
+
+    def lowered(platform):
+        with context():
+            traced = jax.jit(jax.grad(loss, argnums=(0, 1, 2, 3))).trace(x, dt, Bm, Cm)
+            return traced.lower(lowering_platforms=(platform,)).as_text()
+
+    assert ("ssd_intra_bwd" in lowered("tpu")) == kernel
+    assert "tpu_custom_call" not in lowered("cpu")
+    got = jax.grad(loss, argnums=(0, 1, 2, 3))(x, dt, Bm, Cm)
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(mamba2, "_intra_chunk_dispatch", mamba2._intra_chunk)
+        want = jax.grad(loss, argnums=(0, 1, 2, 3))(x, dt, Bm, Cm)
+    for g, w in zip(got, want):
+        np.testing.assert_array_equal(np.asarray(g, np.float32), np.asarray(w, np.float32))
+
+
+def test_ssd_kernel_body_traces_once_per_shape(monkeypatch):
+    """Four calls of the SSD kernels with one shape, lowered for TPU, trace
+    each kernel body at most once (a model has a call site per layer)."""
+    from repro.kernels.ssd import kernel as ssd_k
+
+    traced = collections.Counter()
+    for name in ("_fwd_kernel", "_bwd_kernel"):
+        body = getattr(ssd_k, name)
+        monkeypatch.setattr(ssd_k, name, lambda *a, _b=body, _n=name, **k: (
+            traced.update([_n]), _b(*a, **k))[1])
+    b, nc, L, G, J, P, N = 1, 2, 128, 1, 8, 64, 128
+    ks = jax.random.split(jax.random.PRNGKey(7), 5)
+    args = (rand(ks[0], (b, nc, L, G, N), jnp.bfloat16), rand(ks[1], (b, nc, L, G, N), jnp.bfloat16),
+            jnp.cumsum(-jnp.abs(rand(ks[2], (b, nc, L, G, J))), axis=2),
+            rand(ks[3], (b, nc, L, G, J, P), jnp.bfloat16), jnp.abs(rand(ks[4], (b, nc, L, G, J))))
+
+    def loss(*a):
+        return sum(jnp.sum(ssd_intra(*a) * (i + 1)) for i in range(4))
+
+    lowered = jax.jit(jax.value_and_grad(loss, argnums=(0, 1, 2, 3, 4))).trace(*args).lower(
+        lowering_platforms=("tpu",)).as_text()
+    assert "ssd_intra_fwd" in lowered and "ssd_intra_bwd" in lowered
+    assert traced["_fwd_kernel"] <= 1 and traced["_bwd_kernel"] <= 1, traced
 
 
 # ------------------------------------------------------------------ wkv6

@@ -1,6 +1,7 @@
 """Compiles for a described TPU v5e, with no chip attached: the training
-step at granite-3-2b's published widths and the four Pallas kernels with
-their default ``interpret=False``. What the chip's compiler would refuse,
+step at granite-3-2b's published widths, the Pallas kernels with their
+default ``interpret=False`` and the Mamba-2 mixer's gradient at
+granite-4.0-h-micro's. What the chip's compiler would refuse,
 and a step that would not fit one chip's HBM, fail here.
 
 The topology is described inside a fixture, never at import: only one
@@ -117,11 +118,51 @@ def _wkv6(s):
                   _on(s, (B, T, H, N), bf), _on(s, (B, T, H, N)), _on(s, (H, N)))
 
 
-@pytest.mark.parametrize("build", [_flash, _rmsnorm, _ssm_scan, _wkv6],
-                         ids=["flash_attention", "rmsnorm", "ssm_scan", "wkv6"])
+def _ssd(s):
+    from repro.kernels.ssd.ops import ssd_intra
+
+    # granite-4.0-h-micro at 8k: 64 heads of 64, state 128, one group, chunk 256
+    b, nc, L, G, J, P, N = 2, 32, 256, 1, 64, 64, 128
+    bf = jnp.bfloat16
+    return ssd_intra, (_on(s, (b, nc, L, G, N), bf), _on(s, (b, nc, L, G, N), bf),
+                       _on(s, (b, nc, L, G, J)), _on(s, (b, nc, L, G, J, P), bf),
+                       _on(s, (b, nc, L, G, J)))
+
+
+@pytest.mark.parametrize("build", [_flash, _rmsnorm, _ssm_scan, _wkv6, _ssd],
+                         ids=["flash_attention", "rmsnorm", "ssm_scan", "wkv6", "ssd"])
 def test_kernel_compiles_for_v5e(one_chip, build):
     fn, args = build(one_chip)
     compiled = jax.jit(fn).lower(*args).compile()
     # the Mosaic kernel itself, not the interpreter's XLA loop
     assert "tpu_custom_call" in compiled.as_text()
+    assert _hbm_bytes(compiled) <= V5E_HBM_BYTES
+
+
+def test_mamba2_grad_runs_ssd_kernel_for_v5e(one_chip):
+    """The gradient of a rematerialised Mamba-2 mixer at granite-4.0-h-micro's
+    widths, batch 2 x 8192: the intra-chunk term runs as the SSD kernels,
+    forward (twice, with the recompute) and backward, and no L x L decay
+    mask of float32 is left in the compiled program."""
+    from repro.configs.base import get_config
+    from repro.models import mamba2
+    from repro.models.layers import Layout
+
+    cfg = get_config("granite-4.0-h-micro")
+    m, d = cfg.mamba2, cfg.d_model
+    assert (m.n_heads, m.head_dim, m.d_state, m.n_groups, m.chunk, d) == (64, 64, 128, 1, 256, 2048)
+    layout = Layout(jnp.dtype(jnp.bfloat16), jnp.dtype(jnp.bfloat16))
+    params = jax.eval_shape(lambda k: mamba2.mamba2_init(k, m, d, layout)[0],
+                            jax.random.PRNGKey(0))
+    params = jax.tree.map(lambda a: _on(one_chip, a.shape, a.dtype), params)
+    x = _on(one_chip, (2, 8192, d), jnp.bfloat16)
+
+    def loss(p, x):
+        mixer = jax.checkpoint(lambda p, x: mamba2.mamba2_apply(p, m, x, eps=cfg.norm_eps))
+        return jnp.sum(mixer(p, x).astype(jnp.float32))
+
+    compiled = jax.jit(jax.grad(loss)).lower(params, x).compile()
+    hlo = compiled.as_text()
+    assert re.search(r"ssd_intra_fwd[.\w]* = ", hlo) and re.search(r"ssd_intra_bwd[.\w]* = ", hlo)
+    assert not re.search(r"f32\[[0-9,]*256,256\]", hlo)
     assert _hbm_bytes(compiled) <= V5E_HBM_BYTES
