@@ -33,6 +33,7 @@ import tempfile
 import time
 
 from repro.core import SeaConfig, SeaFS, TierSpec
+from repro.core.ledger import scan_root
 
 _FILE_BYTES = 32 << 20        # one cold model-checkpoint-sized input
 _EXTENT_BYTES = 2 << 20       # 16 extents per file
@@ -168,7 +169,7 @@ def bench_bigger_than_tier(workdir: str) -> tuple[list[dict], dict]:
     snap = fs.telemetry.snapshot()
     tier = fs.hierarchy.cache_tiers[0]
     used = tier.used_bytes(tier.roots[0])
-    scan_used = tier.scan_used_bytes(tier.roots[0])
+    scan_used = sum(scan_root(tier.roots[0]).values())
     fs.transfer.close()
     hot_ratio = snap["extent_hits"] / max(1, chunks)
     derived = {

@@ -1,6 +1,6 @@
 """Adaptive-read-path benchmark: predictive readahead + open fast path.
 
-Three acceptance targets for the adaptive-read-path PR (ISSUE 5):
+Two acceptance targets, plus one measurement:
 
 * **Cold sequential block processing** (the Big Brain-style workload of
   the HSM follow-up paper: a pipeline walks numbered blocks it has
@@ -17,8 +17,7 @@ Three acceptance targets for the adaptive-read-path PR (ISSUE 5):
   never read) under 20% of staged bytes.
 * **Open fast path** — per-call bookkeeping overhead of a read-hit
   ``open``/close (Sea's Python work with the ``open(2)`` syscall
-  stubbed out of both paths) must drop >= 30% with
-  ``open_fast_path=True`` vs the PR-4 path (``open_fast_path=False``).
+  stubbed out), reported ungated.
 
 ``PYTHONPATH=src python -m benchmarks.readahead_bench [--json PATH]``
 prints the same ``name,us_per_call,derived`` CSV as the other benches;
@@ -55,10 +54,9 @@ _MIN_SEQ_SPEEDUP = 2.0
 _MAX_WASTED_RATIO = 0.20
 _FASTPATH_CALLS = 1000
 _FASTPATH_ROUNDS = 9
-_MIN_FASTPATH_REDUCTION = 0.30
 
 
-def _config(workdir: str, *, readahead: bool, fast: bool = True) -> SeaConfig:
+def _config(workdir: str, *, readahead: bool) -> SeaConfig:
     return SeaConfig(
         mount=os.path.join(workdir, "mount"),
         tiers=[
@@ -69,7 +67,6 @@ def _config(workdir: str, *, readahead: bool, fast: bool = True) -> SeaConfig:
         ],
         max_file_size=2 * _BLOCK_BYTES,
         readahead=readahead,
-        open_fast_path=fast,
         transfer_bandwidth_caps={"pfs->*": _BW_STAGE},
     )
 
@@ -187,17 +184,15 @@ class _FakeRaw:
         pass
 
 
-def bench_fastpath(workdir: str) -> tuple[list[dict], float]:
-    """Per-call bookkeeping overhead of a read-hit open/close, fast path
-    on vs off (the PR-4 baseline).
+def bench_fastpath(workdir: str) -> list[dict]:
+    """Per-call bookkeeping overhead of a read-hit open/close.
 
     The ``open(2)`` syscall in sandboxed kernels is bursty at the
     hundreds-of-µs scale — the same magnitude as the overhead being
-    measured — so instead of subtracting a noisy raw-``io.open``
-    baseline, the syscall itself is stubbed out of BOTH paths
+    measured — so the syscall itself is stubbed out
     (``repro.core.seafs.io`` swapped for a fake whose ``open`` returns a
     no-op file). What remains is exactly Sea's per-open Python work
-    (resolution, locking, counts, telemetry): deterministic and
+    (resolution, counts, telemetry): deterministic and
     hardware-independent, in the same spirit as the modelled-bandwidth
     sequential workload."""
     import gc
@@ -205,61 +200,38 @@ def bench_fastpath(workdir: str) -> tuple[list[dict], float]:
 
     from repro.core import seafs as seafs_mod
 
-    def setup(fast: bool):
-        wd = os.path.join(workdir, f"fp_{int(fast)}")
-        shutil.rmtree(wd, ignore_errors=True)
-        fs = SeaFS(_config(wd, readahead=False, fast=fast))
-        p = os.path.join(fs.mount, "hot.bin")
-        with fs.open(p, "wb") as f:
-            f.write(b"x" * 4096)
-        for _ in range(300):  # warmup (and prime the resolver entry)
-            fs.open(p, "rb").close()
-        return fs, p
-
-    fs_slow, p_slow = setup(False)
-    fs_fast, p_fast = setup(True)
+    wd = os.path.join(workdir, "fp")
+    shutil.rmtree(wd, ignore_errors=True)
+    fs = SeaFS(_config(wd, readahead=False))
+    p = os.path.join(fs.mount, "hot.bin")
+    with fs.open(p, "wb") as f:
+        f.write(b"x" * 4096)
+    for _ in range(300):  # warmup (and prime the resolver entry)
+        fs.open(p, "rb").close()
     fake_raw = _FakeRaw()
     fake_io = types.SimpleNamespace(open=lambda *a, **kw: fake_raw)
-    slow_t: list[float] = []
-    fast_t: list[float] = []
+    times: list[float] = []
     orig_io, seafs_mod.io = seafs_mod.io, fake_io
     gc_was_enabled = gc.isenabled()
     gc.disable()
     try:
-        # interleaved median-of-rounds: residual interpreter noise (GC
-        # is off, but timers/threads remain) hits both series alike and
-        # the median discards spike rounds
+        # median-of-rounds: residual interpreter noise (GC is off, but
+        # timers/threads remain) is discarded with the spike rounds
         for _ in range(_FASTPATH_ROUNDS):
-            slow_t.append(
-                _time_loop(lambda: fs_slow.open(p_slow, "rb").close())
-            )
-            fast_t.append(
-                _time_loop(lambda: fs_fast.open(p_fast, "rb").close())
-            )
+            times.append(_time_loop(lambda: fs.open(p, "rb").close()))
     finally:
         seafs_mod.io = orig_io
         if gc_was_enabled:
             gc.enable()
-    assert fs_fast.telemetry.snapshot()["fastpath_opens"] > 0
-    assert fs_slow.telemetry.snapshot()["fastpath_opens"] == 0
-    fs_slow.transfer.close()
-    fs_fast.transfer.close()
-    med = lambda xs: sorted(xs)[len(xs) // 2]  # noqa: E731
-    slow_o, fast_o = med(slow_t), med(fast_t)
-    reduction = 1.0 - fast_o / slow_o
-    rows = [
-        {
-            "name": "open_read_hit_pr4_overhead",
-            "us_per_call": round(slow_o, 2),
-            "derived": "open_fast_path=off",
-        },
+    assert fs.telemetry.snapshot()["fastpath_opens"] > 0
+    fs.transfer.close()
+    return [
         {
             "name": "open_read_hit_fastpath_overhead",
-            "us_per_call": round(fast_o, 2),
-            "derived": f"reduction={reduction:.2f}",
+            "us_per_call": round(sorted(times)[len(times) // 2], 2),
+            "derived": "",
         },
     ]
-    return rows, reduction
 
 
 def main(argv: list[str] | None = None) -> None:
@@ -277,7 +249,7 @@ def main(argv: list[str] | None = None) -> None:
         print("name,us_per_call,derived")
         seq_rows, speedup = bench_sequential(workdir)
         waste_rows, wasted_ratio = bench_random_waste(workdir)
-        fp_rows, reduction = bench_fastpath(workdir)
+        fp_rows = bench_fastpath(workdir)
         rows = seq_rows + waste_rows + fp_rows
         for row in rows:
             print(f"{row['name']},{row['us_per_call']},{row['derived']}")
@@ -288,15 +260,7 @@ def main(argv: list[str] | None = None) -> None:
             f"acceptance_wasted_ratio,{wasted_ratio:.2f},"
             f"<{_MAX_WASTED_RATIO}_required"
         )
-        print(
-            f"acceptance_fastpath_reduction,{reduction:.2f},"
-            f">={_MIN_FASTPATH_REDUCTION}_required"
-        )
-        ok = (
-            speedup >= _MIN_SEQ_SPEEDUP
-            and wasted_ratio < _MAX_WASTED_RATIO
-            and reduction >= _MIN_FASTPATH_REDUCTION
-        )
+        ok = speedup >= _MIN_SEQ_SPEEDUP and wasted_ratio < _MAX_WASTED_RATIO
         if json_path:
             with open(json_path, "w") as f:
                 json.dump(
@@ -304,7 +268,6 @@ def main(argv: list[str] | None = None) -> None:
                         "rows": rows,
                         "cold_seq_speedup": round(speedup, 2),
                         "wasted_ratio": round(wasted_ratio, 3),
-                        "fastpath_overhead_reduction": round(reduction, 3),
                         "elapsed_s": round(
                             time.perf_counter() - t_start, 2
                         ),

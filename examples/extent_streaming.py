@@ -28,6 +28,7 @@ import shutil
 import tempfile
 
 from repro.core import SeaConfig, SeaFS, TierSpec
+from repro.core.ledger import scan_root
 
 FILE_BYTES = 32 << 20    # the cold input: 2x the cache tier
 EXTENT_BYTES = 4 << 20   # 8 blocks per file
@@ -59,7 +60,7 @@ def report(fs: SeaFS, phase: str) -> None:
     snap = fs.telemetry.snapshot()
     tier = fs.hierarchy.cache_tiers[0]
     root = tier.roots[0]
-    used, walk = tier.used_bytes(root), tier.scan_used_bytes(root)
+    used, walk = tier.used_bytes(root), sum(scan_root(root).values())
     print(f"\n-- {phase} --")
     for k in (
         "extent_hits",

@@ -14,7 +14,7 @@ from __future__ import annotations
 
 import configparser
 import os
-from dataclasses import dataclass, field, replace
+from dataclasses import dataclass, field, fields, replace
 
 from .tiers import Hierarchy, TierSpec
 
@@ -22,6 +22,28 @@ from .tiers import Hierarchy, TierSpec
 FLUSHLIST_NAME = ".sea_flushlist"
 EVICTLIST_NAME = ".sea_evictlist"
 PREFETCHLIST_NAME = ".sea_prefetchlist"
+
+
+def _read_scalars(
+    section: configparser.SectionProxy, cls: type, skip: tuple[str, ...] = ()
+) -> dict:
+    """Keyword arguments for dataclass ``cls`` from the keys of one INI
+    section that name its scalar fields, each parsed by the field's
+    declared type (annotations are strings under ``from __future__ import
+    annotations``). An absent key passes nothing, so the dataclass holds
+    the only copy of every default; unknown keys are ignored."""
+    getters = {
+        "bool": section.getboolean,
+        "int": section.getint,
+        "int | None": section.getint,
+        "float": section.getfloat,
+        "str": section.get,
+    }
+    return {
+        f.name: getters[f.type](f.name)
+        for f in fields(cls)
+        if f.type in getters and f.name not in skip and f.name in section
+    }
 
 
 @dataclass
@@ -39,12 +61,9 @@ class SeaConfig:
     flush_workers: int = 2              # worker pool size: flushes of independent
                                         # keys proceed concurrently
     #: capacity-accounting ledger (O(1) placement hot path)
-    capacity_ledger: bool = True        # False = seed's stateless per-call rescan
     ledger_reconcile_interval_s: float = 5.0  # staleness bound for absorbing
                                               # external writers via re-walk
     #: namespace resolver (O(1) resolution hot path, verify-on-hit)
-    resolver_cache: bool = True         # False = seed's O(tiers*roots) probe
-                                        # cascade on every resolution
     resolver_negative_ttl_s: float = 0.05  # how long a confirmed miss is
                                            # trusted (read-miss storms)
     resolver_verify_window_s: float = 0.05  # how long a verified hit skips
@@ -53,8 +72,6 @@ class SeaConfig:
                                             # on ENOENT either way)
     #: data plane (chunked streaming transfer engine — every byte moved
     #: between tiers goes through repro.core.transfer.TransferEngine)
-    transfer_engine: bool = True        # False = seed's whole-file shutil copy
-                                        # (atomic commit + accounting kept)
     transfer_workers: int = 4           # bounded parallel transfer pool size
     transfer_chunk_bytes: int = 32 << 20  # chunk size of the streaming copy
                                         # loop (zero-copy syscalls: large
@@ -111,10 +128,6 @@ class SeaConfig:
                                         # sequence (adaptive, 1..depth)
     readahead_min_confidence: float = 0.5  # empirical confidence a predicted
                                            # key needs before staging
-    open_fast_path: bool = True         # read-hit opens skip key locks and
-                                        # take batched per-thread telemetry
-                                        # (False = PR-4 open path, benchmark
-                                        # baseline)
     #: extent-granular data plane (block-level placement on cache tiers)
     extent_map: bool = False            # True = key -> extent map on cache
                                         # tiers: sparse partial replicas,
@@ -191,10 +204,6 @@ class SeaConfig:
             raise ValueError("readahead_min_confidence must be in [0, 1]")
         if self.extent_bytes <= 0:
             raise ValueError("extent_bytes must be positive")
-        if self.extent_map and not self.transfer_engine:
-            raise ValueError("extent_map requires transfer_engine=True")
-        if self.shared_ledger and not self.capacity_ledger:
-            raise ValueError("shared_ledger requires capacity_ledger=True")
         if self.federation and not self.shared_ledger:
             raise ValueError("federation requires shared_ledger=True")
         if self.federation_heartbeat_s <= 0:
@@ -221,7 +230,6 @@ class SeaConfig:
     def build_hierarchy(self) -> Hierarchy:
         return Hierarchy.from_specs(
             list(self.tiers),
-            use_ledger=self.capacity_ledger,
             shared=self.shared_ledger,
             reconcile_interval_s=self.ledger_reconcile_interval_s,
         )
@@ -273,10 +281,7 @@ class SeaConfig:
                 TierSpec(
                     name=section[len("tier.") :],
                     roots=tuple(x.strip() for x in t["roots"].split(",")),
-                    read_bw=t.getfloat("read_bw", 0.0),
-                    write_bw=t.getfloat("write_bw", 0.0),
-                    capacity=t.getint("capacity", fallback=None),
-                    persistent=t.getboolean("persistent", fallback=False),
+                    **_read_scalars(t, TierSpec, skip=("name",)),
                 )
             )
         base = os.path.dirname(os.path.abspath(path))
@@ -293,51 +298,11 @@ class SeaConfig:
         return cls(
             mount=sea["mount"],
             tiers=tiers,
-            max_file_size=sea.getint("max_file_size", 1 << 20),
-            n_procs=sea.getint("n_procs", 1),
-            flush_workers=sea.getint("flush_workers", 2),
-            capacity_ledger=sea.getboolean("capacity_ledger", True),
-            ledger_reconcile_interval_s=sea.getfloat(
-                "ledger_reconcile_interval_s", 5.0
-            ),
-            resolver_cache=sea.getboolean("resolver_cache", True),
-            resolver_negative_ttl_s=sea.getfloat("resolver_negative_ttl_s", 0.05),
-            resolver_verify_window_s=sea.getfloat(
-                "resolver_verify_window_s", 0.05
-            ),
-            shared_ledger=sea.getboolean("shared_ledger", False),
-            leader_heartbeat_s=sea.getfloat("leader_heartbeat_s", 0.5),
-            federation=sea.getboolean("federation", False),
-            federation_node=sea.get("federation_node", ""),
-            federation_heartbeat_s=sea.getfloat("federation_heartbeat_s", 1.0),
-            federation_node_ttl_s=sea.getfloat("federation_node_ttl_s", 10.0),
-            transfer_engine=sea.getboolean("transfer_engine", True),
-            transfer_workers=sea.getint("transfer_workers", 4),
-            transfer_chunk_bytes=sea.getint("transfer_chunk_bytes", 32 << 20),
-            transfer_retries=sea.getint("transfer_retries", 2),
-            transfer_backoff_s=sea.getfloat("transfer_backoff_s", 0.02),
-            transfer_deadline_s=sea.getfloat("transfer_deadline_s", 0.0),
-            health_window_s=sea.getfloat("health_window_s", 30.0),
-            health_error_threshold=sea.getfloat("health_error_threshold", 0.5),
-            health_min_events=sea.getint("health_min_events", 4),
-            health_open_s=sea.getfloat("health_open_s", 2.0),
-            faults=sea.get("faults", ""),
-            fault_seed=sea.getint("fault_seed", 0),
             transfer_bandwidth_caps=caps,
-            readahead=sea.getboolean("readahead", False),
-            readahead_depth=sea.getint("readahead_depth", 4),
-            readahead_min_confidence=sea.getfloat(
-                "readahead_min_confidence", 0.5
-            ),
-            open_fast_path=sea.getboolean("open_fast_path", True),
-            extent_map=sea.getboolean("extent_map", False),
-            extent_bytes=sea.getint("extent_bytes", 32 << 20),
-            checkpoint_async=sea.getboolean("checkpoint_async", True),
-            checkpoint_workers=sea.getint("checkpoint_workers", 2),
-            device_prefetch=sea.getint("device_prefetch", 2),
             flushlist=_read_list(FLUSHLIST_NAME),
             evictlist=_read_list(EVICTLIST_NAME),
             prefetchlist=_read_list(PREFETCHLIST_NAME),
+            **_read_scalars(sea, cls, skip=("mount",)),
         )
 
     @classmethod
@@ -359,8 +324,6 @@ class SeaConfig:
             shared_ledger=env.get("SEA_SHARED_LEDGER", "0") not in ("0", "", "false"),
             federation=env.get("SEA_FEDERATION", "0") not in ("0", "", "false"),
             federation_node=env.get("SEA_FEDERATION_NODE", ""),
-            resolver_cache=env.get("SEA_RESOLVER_CACHE", "1")
-            not in ("0", "", "false"),
             readahead=env.get("SEA_READAHEAD", "0") not in ("0", "", "false"),
             extent_map=env.get("SEA_EXTENT_MAP", "0") not in ("0", "", "false"),
             extent_bytes=int(env.get("SEA_EXTENT_BYTES", 32 << 20)),

@@ -9,10 +9,10 @@ concurrent writer ("the number of threads multiplied by the file size does
 not exceed storage space"). Same-level roots are picked by random shuffle:
 no metadata server, no locking — decentralization over optimal packing.
 
-With the capacity ledger attached (the default), ``free`` is an O(1)
-counter lookup and additionally discounts *in-flight write reservations*:
-each open-for-write holds a ``max_file_size`` budget against its root
-(:meth:`reserve_write`) until the close commits the actual size. This
+``free`` is an O(1) capacity-ledger lookup and additionally discounts
+*in-flight write reservations*: each open-for-write holds a
+``max_file_size`` budget against its root (:meth:`reserve_write`) until
+the close commits the actual size. This
 tracks the ``n_procs * max_file_size`` headroom per-root as writes happen,
 instead of re-deriving it from a filesystem walk on every call.
 """
@@ -130,8 +130,8 @@ class PlacementPolicy:
         root = tier.roots[0]
         return tier, root, self.reserve_write(tier, root)
 
-    # -- in-flight write budgets (ledger-backed; no-ops when stateless) -----
-    def reserve_write(self, tier: Tier, root: str) -> Reservation | None:
+    # -- in-flight write budgets (ledger-backed) ----------------------------
+    def reserve_write(self, tier: Tier, root: str) -> Reservation:
         """Hold a worst-case (``max_file_size``) budget for one in-flight
         write so concurrent writers cannot collectively over-commit a root
         whose bytes have not reached the disk yet."""
@@ -146,8 +146,6 @@ class PlacementPolicy:
         lost race the caller re-selects. Uncapped roots (statvfs-backed)
         cannot meaningfully over-commit at this scale, so they reserve
         unconditionally."""
-        if tier.ledger is None:
-            return True, None
         if tier.spec.capacity is None:
             return True, tier.reserve_write(root, self.max_file_size)
         res = tier.ledger.try_reserve(

@@ -46,8 +46,7 @@ Every mutation path (write placement, close/commit, ``remove``,
 ``rename``, LRU eviction, flusher flush/evict/move, prefetch staging,
 ``wipe``) notes or invalidates entries; the index never needs to be
 trusted blindly, so a stale entry costs one wasted ``lstat``, never a
-stale read. ``SeaConfig(resolver_cache=False)`` restores the seed's pure
-probe cascade (the benchmark baseline).
+stale read.
 """
 
 from __future__ import annotations
@@ -107,14 +106,12 @@ class Resolver:
         hierarchy: Hierarchy,
         telemetry=None,
         *,
-        enabled: bool = True,
         negative_ttl_s: float = 0.05,
         verify_window_s: float = 0.05,
         n_shards: int = 16,
     ):
         self.hierarchy = hierarchy
         self.telemetry = telemetry
-        self.enabled = enabled
         self.negative_ttl_s = max(float(negative_ttl_s), 0.0)
         self.verify_window_s = max(float(verify_window_s), 0.0)
         self._shards: list[dict[str, object]] = [{} for _ in range(n_shards)]
@@ -129,13 +126,12 @@ class Resolver:
 
         #: bound by SeaFS when the extent plane is enabled — the extent
         #: maps are placement state (like the tiers themselves), not a
-        #: cache, so resolve_extent serves even with ``enabled=False``
+        #: cache
         self.extent_store = None
 
         #: bound by SeaFS when cache federation is enabled — the third
         #: resolution tier (local hit -> peer hit -> base fallback); like
-        #: extent maps, the registry is cluster state and serves even
-        #: with ``enabled=False``
+        #: extent maps, the registry is cluster state
         self.federation = None
 
         # don't cache a directory whose mtime is this close to "now": a
@@ -194,8 +190,6 @@ class Resolver:
         must treat their own ENOENT as a failed verify and call
         :meth:`refresh` (operation-as-verify).
         """
-        if not self.enabled:
-            return self.hierarchy.locate(key)
         i = self._shard_index(key)
         shard = self._shards[i]
         lock = self._locks[i]
@@ -254,7 +248,7 @@ class Resolver:
         ENOENT sends the caller to the full slow path, which heals). With
         ``verify_window_s == 0`` (strict verify-on-hit) this never hits,
         so the fast path composes with the strict discipline."""
-        if not self.enabled or self.verify_window_s <= 0.0:
+        if self.verify_window_s <= 0.0:
             return None
         e = self._shards[self._shard_index(key)].get(key)
         if (
@@ -316,8 +310,6 @@ class Resolver:
         """A caller's own operation hit ENOENT on a resolved path (the
         operation doubled as the verify and failed): drop the entry,
         count the verify failure, and re-scan from scratch."""
-        if not self.enabled:
-            return self.hierarchy.locate(key)
         i = self._shard_index(key)
         with self._locks[i]:
             self._shards[i].pop(key, None)
@@ -336,8 +328,6 @@ class Resolver:
         forces the first read hit to verify. Entries are advisory: if the
         caller never materializes the file, the next resolve's verify
         simply falls back to the scan."""
-        if not self.enabled:
-            return
         i = self._shard_index(key)
         with self._locks[i]:
             self._gens[i] += 1  # a racing scan must not clobber this note
@@ -354,8 +344,6 @@ class Resolver:
         index believes about it (one invalidation covers all replicas).
         A scan racing this mutation is fenced by the shard generation:
         its (possibly pre-mutation) result will not be stored."""
-        if not self.enabled:
-            return
         i = self._shard_index(key)
         with self._locks[i]:
             self._gens[i] += 1
@@ -426,19 +414,16 @@ class Resolver:
         ``listdir`` + set union."""
         key = "" if key == "." else key
         candidates = self._dir_candidates(key)
-        stamps = None
-        if self.enabled:
-            with self._dir_lock:
-                e = self._dirs.get(key)
-            # signature FIRST, union second: a mutation racing the walk
-            # makes the stored stamp stale, so the next hit re-verifies —
-            # never the other way around (a post-walk stamp could mask a
-            # missed entry)
-            stamps = self._dir_signature(candidates)
-            if e is not None and stamps == e.stamps:
-                self._record("record_dir_resolve", hit=True)
-                return set(e.entries)
-            self._record("record_dir_resolve", hit=False)
+        with self._dir_lock:
+            e = self._dirs.get(key)
+        # signature FIRST, union second: a mutation racing the walk makes
+        # the stored stamp stale, so the next hit re-verifies — never the
+        # other way around (a post-walk stamp could mask a missed entry)
+        stamps = self._dir_signature(candidates)
+        if e is not None and stamps == e.stamps:
+            self._record("record_dir_resolve", hit=True)
+            return set(e.entries)
+        self._record("record_dir_resolve", hit=False)
         seen: set[str] = set()
         found = False
         for p in candidates:
@@ -450,20 +435,18 @@ class Resolver:
             seen.update(names)
         if not found:
             return None
-        if self.enabled and not self._racy_stamps(stamps):
+        if not self._racy_stamps(stamps):
             with self._dir_lock:
                 if len(self._dirs) >= self._DIRS_MAX:
                     self._dirs.clear()
                 self._dirs[key] = _DirEntry(stamps, frozenset(seen))
         return seen
 
-    def _racy_stamps(self, stamps: tuple | None) -> bool:
+    def _racy_stamps(self, stamps: tuple) -> bool:
         """True when any contributing directory's mtime is within the
         racy window of "now": a mutation landing in the same mtime tick
         (coarse-granularity filesystems) would be invisible to the
         signature check, so such a union must not be cached."""
-        if stamps is None:
-            return True
         now_ns = time.time_ns()
         return any(
             s is not None and now_ns - s[0] < self._racy_dir_ns for s in stamps
@@ -475,17 +458,16 @@ class Resolver:
         index when its signature still verifies."""
         key = "" if key == "." else key
         candidates = self._dir_candidates(key)
-        if self.enabled:
-            with self._dir_lock:
-                e = self._dirs.get(key)
-            if e is not None:
-                sig = self._dir_signature(candidates)
-                if sig == e.stamps:
-                    self._record("record_dir_resolve", hit=True)
-                    for p, s in zip(candidates, sig):
-                        if s is not None:
-                            return p
-                    return None
+        with self._dir_lock:
+            e = self._dirs.get(key)
+        if e is not None:
+            sig = self._dir_signature(candidates)
+            if sig == e.stamps:
+                self._record("record_dir_resolve", hit=True)
+                for p, s in zip(candidates, sig):
+                    if s is not None:
+                        return p
+                return None
         for p in candidates:
             if os.path.isdir(p):
                 return p

@@ -316,8 +316,7 @@ class SeaFS:
         self.config = config
         self.hierarchy: Hierarchy = config.build_hierarchy()
         self.telemetry = telemetry or Telemetry()
-        if self.hierarchy.ledger is not None:
-            self.hierarchy.ledger.telemetry = self.telemetry
+        self.hierarchy.ledger.telemetry = self.telemetry
         # failure-domain layer: per-root sliding-window health feeding a
         # circuit breaker; quarantined cache roots drop out of placement
         # until a half-open probe succeeds (the base tier is never gated)
@@ -337,7 +336,6 @@ class SeaFS:
         self.resolver = Resolver(
             self.hierarchy,
             self.telemetry,
-            enabled=config.resolver_cache,
             negative_ttl_s=config.resolver_negative_ttl_s,
             verify_window_s=config.resolver_verify_window_s,
         )
@@ -362,7 +360,6 @@ class SeaFS:
         self._key_locks: dict[str, threading.RLock] = {}
         self._close_listeners: list = []  # flusher subscribes here
         self._access_clock: dict[str, float] = {}  # LRU bookkeeping (opt-in)
-        self._fast_open = bool(getattr(config, "open_fast_path", True))
         self._readahead = bool(getattr(config, "readahead", False))
         # extent-granular data plane (opt-in): partial sparse replicas on
         # cache tiers, per-extent staging/eviction, streaming reads
@@ -645,8 +642,6 @@ class SeaFS:
         either a complete committed file or nothing (the atomic-commit
         invariant of the data plane); it can never see a mid-flush move
         as a partial file or a spurious miss."""
-        if not self._fast_open:
-            return None
         key = self._fast_key(path)
         if not key:
             return None
@@ -1733,7 +1728,7 @@ class SeaFS:
 
     def _admit_extent(self, tier: Tier, root: str, nbytes: int):
         """Atomic per-extent admission. Returns (admitted, reservation)."""
-        if tier.spec.capacity is None or tier.ledger is None:
+        if tier.spec.capacity is None:
             if not tier.admissible(root, required=nbytes, nbytes=nbytes):
                 return False, None
             return True, tier.reserve_write(root, nbytes)

@@ -13,15 +13,9 @@ from __future__ import annotations
 
 import os
 import shutil
-from dataclasses import dataclass, field
+from dataclasses import dataclass
 
-from .ledger import (
-    LEDGER_DIRNAME,
-    TMP_SUFFIX,
-    CapacityLedger,
-    Reservation,
-    file_disk_usage,
-)
+from .ledger import CapacityLedger, Reservation
 from .shared_ledger import SharedCapacityLedger
 
 
@@ -51,18 +45,16 @@ class TierSpec:
 class Tier:
     """A live tier: spec + capacity accounting over its roots.
 
-    With a :class:`~repro.core.ledger.CapacityLedger` attached (the
-    default through :class:`Hierarchy`), ``used_bytes``/``free_bytes``
-    are O(1) counter lookups; the full ``os.walk`` survives only as the
-    ledger's reconcile path. ``ledger=None`` restores the seed's
-    stateless per-call rescan (used by benchmarks as the baseline).
+    ``used_bytes``/``free_bytes`` are O(1) lookups in the attached
+    :class:`~repro.core.ledger.CapacityLedger`; the full ``os.walk``
+    survives only as the ledger's reconcile path.
     """
 
     def __init__(
         self,
         spec: TierSpec,
         level: int,
-        ledger: CapacityLedger | SharedCapacityLedger | None = None,
+        ledger: CapacityLedger | SharedCapacityLedger,
     ):
         self.spec = spec
         self.level = level
@@ -83,38 +75,13 @@ class Tier:
         return self.spec.persistent
 
     # -- capacity ----------------------------------------------------------
-    def scan_used_bytes(self, root: str) -> int:
-        """Bytes used under one root by a full re-scan (the seed's per-call
-        behaviour; now the reconcile/baseline path only). In-flight
-        ``.sea_tmp`` staging files are not data: counting one that a
-        failed transfer later unlinks would overstate ``used`` with bytes
-        nothing ever removes. Sizes are sparse-aware (``file_disk_usage``)
-        so a partial extent replica counts its staged blocks, not the
-        holes."""
-        total = 0
-        for dirpath, dirnames, filenames in os.walk(root):
-            if LEDGER_DIRNAME in dirnames:
-                dirnames.remove(LEDGER_DIRNAME)
-            for fn in filenames:
-                if fn.endswith(TMP_SUFFIX):
-                    continue
-                try:
-                    total += file_disk_usage(os.path.join(dirpath, fn))
-                except OSError:
-                    pass
-        return total
-
     def used_bytes(self, root: str) -> int:
-        """Bytes used under one root — O(1) via the ledger when attached."""
-        if self.ledger is not None:
-            return self.ledger.used_bytes(root)
-        return self.scan_used_bytes(root)
+        """Bytes used under one root — an O(1) ledger lookup."""
+        return self.ledger.used_bytes(root)
 
     def reserved_bytes(self, root: str) -> int:
         """In-flight write budget currently held against one root."""
-        if self.ledger is not None:
-            return self.ledger.reserved_bytes(root)
-        return 0
+        return self.ledger.reserved_bytes(root)
 
     def free_bytes(self, root: str) -> int:
         """Free bytes on one root, honouring the configured cap if any and
@@ -149,39 +116,32 @@ class Tier:
             required, reserved + nbytes
         )
 
-    # -- ledger notifications (no-ops when running stateless) ---------------
+    # -- ledger notifications ----------------------------------------------
     def note_written(self, root: str, key: str, nbytes: int) -> None:
-        if self.ledger is not None:
-            self.ledger.note_written(root, key, nbytes)
+        self.ledger.note_written(root, key, nbytes)
 
     def note_removed(self, root: str, key: str) -> None:
-        if self.ledger is not None:
-            self.ledger.note_removed(root, key)
+        self.ledger.note_removed(root, key)
 
-    def reserve_write(self, root: str, nbytes: int) -> Reservation | None:
-        if self.ledger is not None:
-            return self.ledger.reserve(root, nbytes)
-        return None
+    def reserve_write(self, root: str, nbytes: int) -> Reservation:
+        return self.ledger.reserve(root, nbytes)
 
     def commit_write(
         self, res: Reservation | None, root: str, key: str, nbytes: int
     ) -> None:
-        if self.ledger is None:
-            return
         if res is not None:
             self.ledger.commit(res, key, nbytes)
         else:
             self.ledger.note_written(root, key, nbytes)
 
     def release_write(self, res: Reservation | None) -> None:
-        if self.ledger is not None and res is not None:
+        if res is not None:
             self.ledger.release(res)
 
     def reconcile(self) -> None:
         """On-demand reconciliation of every root of this tier."""
-        if self.ledger is not None:
-            for root in self.roots:
-                self.ledger.reconcile(root)
+        for root in self.roots:
+            self.ledger.reconcile(root)
 
     def root_of(self, path: str) -> str | None:
         """The root of this tier that ``path`` lives under, if any."""
@@ -204,8 +164,7 @@ class Tier:
             if os.path.isdir(root):
                 shutil.rmtree(root, ignore_errors=True)
             os.makedirs(root, exist_ok=True)
-            if self.ledger is not None:
-                self.ledger.forget(root)
+            self.ledger.forget(root)
 
     def __repr__(self) -> str:  # pragma: no cover
         return f"Tier(level={self.level}, name={self.name!r}, roots={self.roots})"
@@ -216,16 +175,14 @@ class Hierarchy:
     """Ordered collection of tiers, fastest (level 0) first. All tiers
     share one :class:`CapacityLedger` (sharded internally by root)."""
 
-    tiers: list[Tier] = field(default_factory=list)
-    ledger: CapacityLedger | SharedCapacityLedger | None = None
+    tiers: list[Tier]
+    ledger: CapacityLedger | SharedCapacityLedger
 
     @classmethod
     def from_specs(
         cls,
         specs: list[TierSpec],
         *,
-        ledger: CapacityLedger | SharedCapacityLedger | None = None,
-        use_ledger: bool = True,
         shared: bool = False,
         reconcile_interval_s: float = 5.0,
     ) -> "Hierarchy":
@@ -236,11 +193,10 @@ class Hierarchy:
             )
         if not specs[-1].persistent:
             specs[-1].persistent = True  # last tier is the base by definition
-        if ledger is None and use_ledger:
-            # shared: file-backed, fcntl-guarded accounting every process
-            # mounting this hierarchy sees; default: in-process counters
-            cls_ledger = SharedCapacityLedger if shared else CapacityLedger
-            ledger = cls_ledger(reconcile_interval_s=reconcile_interval_s)
+        # shared: file-backed, fcntl-guarded accounting every process
+        # mounting this hierarchy sees; default: in-process counters
+        cls_ledger = SharedCapacityLedger if shared else CapacityLedger
+        ledger = cls_ledger(reconcile_interval_s=reconcile_interval_s)
         return cls([Tier(s, i, ledger) for i, s in enumerate(specs)], ledger)
 
     def owner_of(self, path: str) -> tuple[Tier, str] | None:

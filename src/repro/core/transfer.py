@@ -34,10 +34,6 @@ al. 2021):
   loop so background flushes can be capped below application I/O.
 * **Retry with backoff** on transient ``OSError``; cooperative
   **cancellation** between chunks.
-
-``SeaConfig(transfer_engine=False)`` keeps the atomic-commit and
-accounting semantics but moves bytes with one whole-file
-``shutil.copyfile`` — the seed's behaviour, kept for benchmarking.
 """
 
 from __future__ import annotations
@@ -227,7 +223,6 @@ class TransferEngine:
         telemetry: Telemetry | None = None,
         policy=None,
     ):
-        self.enabled = bool(getattr(config, "transfer_engine", True))
         self.chunk_bytes = int(getattr(config, "transfer_chunk_bytes", 32 << 20))
         self.n_workers = max(1, int(getattr(config, "transfer_workers", 4)))
         self.retries = max(0, int(getattr(config, "transfer_retries", 2)))
@@ -742,9 +737,7 @@ class TransferEngine:
         return copied, impl
 
     def _admit(self, tier: Tier, root: str, nbytes: int, *, mode: str):
-        if mode == "reserve" or tier.ledger is None:
-            return tier.reserve_write(root, nbytes)
-        if tier.spec.capacity is None:
+        if mode == "reserve" or tier.spec.capacity is None:
             return tier.reserve_write(root, nbytes)
         required = (
             self.policy.required_bytes if self.policy is not None else nbytes
@@ -813,11 +806,6 @@ class TransferEngine:
 
     def _copy_once(self, src, tmp, pair, cancel, on_chunk) -> tuple[int, str]:
         """One staging attempt: chunk loop into ``tmp`` + size verify."""
-        if not self.enabled:
-            # seed behaviour for benchmarking: one whole-file copy (the
-            # atomic rename + accounting above still apply)
-            shutil.copyfile(src, tmp)
-            return os.path.getsize(tmp), "shutil"
         bucket = self._bucket(pair)
         copied = 0
         impl = (

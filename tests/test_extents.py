@@ -26,6 +26,7 @@ import pytest
 
 from repro.core import PART_SUFFIX, SeaConfig, SeaFS, SeaMount, TierSpec
 from repro.core.extents import extent_token, journal_path, split_extent_token
+from repro.core.ledger import scan_root
 
 EXT = 128 << 10   # extent size: small, 4096-aligned (exact sparse accounting)
 CHUNK = 16 << 10  # transfer chunk: several chunks per extent
@@ -143,11 +144,6 @@ def test_extent_map_off_never_creates_part_files(tmp_path):
     assert fs.extents is None
 
 
-def test_extent_map_requires_transfer_engine(tmp_path):
-    with pytest.raises(ValueError):
-        make_config(tmp_path, transfer_engine=False)
-
-
 # ------------------------------------------------- capacity / ledger behaviour
 def test_file_bigger_than_tier_streams_with_walk_consistent_ledger(tmp_path):
     cap = 4 * EXT
@@ -166,13 +162,13 @@ def test_file_bigger_than_tier_streams_with_walk_consistent_ledger(tmp_path):
     tier = fs.hierarchy.cache_tiers[0]
     root = tier.roots[0]
     used = tier.used_bytes(root)
-    assert used == tier.scan_used_bytes(root)  # ledger == the walk
+    assert used == sum(scan_root(root).values())  # ledger == the walk
     assert used <= cap
     # random access into a punched region re-faults correctly
     with fs.open(p, "rb") as f:
         f.seek(100)
         assert f.read(4096) == data[100 : 100 + 4096]
-    assert tier.used_bytes(root) == tier.scan_used_bytes(root)
+    assert tier.used_bytes(root) == sum(scan_root(root).values())
 
 
 def test_getsize_and_stat_report_logical_size_while_partial(tmp_path):
@@ -195,7 +191,7 @@ def test_scan_used_bytes_counts_staged_blocks_not_holes(tmp_path):
         f.read(100)  # one extent staged, seven holes
     tier = fs.hierarchy.cache_tiers[0]
     root = tier.roots[0]
-    scanned = tier.scan_used_bytes(root)
+    scanned = sum(scan_root(root).values())
     staged = ext_snap(fs)["extent_staged_bytes"]
     assert staged < 8 * EXT  # partial by construction
     assert scanned == staged  # holes cost nothing; no double-count
@@ -227,7 +223,7 @@ def test_injected_fault_mid_extent_leaves_no_torn_valid(tmp_path):
     # the admission reservation was released, not leaked
     tier = fs.hierarchy.cache_tiers[0]
     assert tier.reserved_bytes(tier.roots[0]) == 0
-    assert tier.used_bytes(tier.roots[0]) == tier.scan_used_bytes(tier.roots[0])
+    assert tier.used_bytes(tier.roots[0]) == sum(scan_root(tier.roots[0]).values())
     # with the fault gone, a later read re-faults and heals the extent
     fs.transfer.chunk_hook = None
     with fs.open(p, "rb") as f:
@@ -324,9 +320,7 @@ def test_truncate_updates_ledger_and_invalidates_extents(tmp_path):
     assert not part_files(fs.hierarchy.cache_tiers[0].roots[0])
     assert fs.getsize(p) == EXT
     base_tier = fs.hierarchy.base
-    assert base_tier.used_bytes(base_tier.roots[0]) == base_tier.scan_used_bytes(
-        base_tier.roots[0]
-    )
+    assert base_tier.used_bytes(base_tier.roots[0]) == sum(scan_root(base_tier.roots[0]).values())
 
 
 def test_truncate_missing_key_raises_enoent(tmp_path):
@@ -345,7 +339,7 @@ def test_ftruncate_settles_accounting_for_sea_fds(tmp_path):
     assert fs.getsize(p) == 4096
     tier, real = fs.resolver.resolve("w.bin")
     root = tier.root_of(real)
-    assert tier.used_bytes(root) == tier.scan_used_bytes(root)
+    assert tier.used_bytes(root) == sum(scan_root(root).values())
 
 
 def test_os_truncate_intercepted_under_mount(tmp_path):

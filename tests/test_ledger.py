@@ -18,7 +18,7 @@ import pytest
 
 from repro.core import Sea, SeaConfig, SeaFS, TierSpec
 from repro.core.flusher import Flusher
-from repro.core.ledger import CapacityLedger
+from repro.core.ledger import CapacityLedger, scan_root
 from repro.core.tiers import Tier
 
 
@@ -256,7 +256,7 @@ def test_concurrent_writers_never_overcommit_capped_root(tmp_path):
     assert not errs
     # the capped tmpfs root must never physically exceed its capacity
     tier0 = fs.hierarchy.tiers[0]
-    assert tier0.scan_used_bytes(tier0.roots[0]) <= cap
+    assert sum(scan_root(tier0.roots[0]).values()) <= cap
     assert_ledger_matches_walk(fs)
 
 
@@ -343,18 +343,6 @@ def test_ledger_telemetry_counters(tmp_path):
     snap = fs.telemetry.snapshot()
     assert snap["ledger_hits"] >= 5
     assert snap["ledger_reconciles"] >= 1  # the initial walk of the root
-
-
-def test_capacity_ledger_can_be_disabled(tmp_path):
-    """capacity_ledger=False restores the seed's stateless per-call walk."""
-    cfg = make_config(tmp_path, capacity_ledger=False)
-    cfg.tiers[0].capacity = 1 << 20
-    fs = SeaFS(cfg)
-    assert fs.hierarchy.ledger is None
-    p = os.path.join(fs.mount, "x.bin")
-    fs.write_bytes(p, b"x" * 100)
-    assert fs.where(p) == "tmpfs"
-    assert fs.telemetry.snapshot()["ledger_hits"] == 0
 
 
 # ------------------------------------------------------------- simulator model

@@ -286,16 +286,6 @@ def test_fast_path_serves_warm_rereads(tmp_path):
     assert snap["tiers"]["tmpfs"]["bytes_read"] >= 8 * 256
 
 
-def test_fast_path_toggle_restores_pr4_path(tmp_path):
-    fs = SeaFS(make_config(tmp_path, open_fast_path=False, readahead=False))
-    p = os.path.join(fs.mount, "warm.bin")
-    fs.write_bytes(p, b"w" * 256)
-    for _ in range(5):
-        with fs.open(p, "rb") as f:
-            assert f.read() == b"w" * 256
-    assert fs.telemetry.snapshot()["fastpath_opens"] == 0
-
-
 def test_fast_path_respects_strict_verify_window(tmp_path):
     fs = SeaFS(make_config(tmp_path, resolver_verify_window_s=0.0,
                            readahead=False))

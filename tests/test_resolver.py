@@ -67,19 +67,6 @@ def test_hit_path_skips_probe_cascade(tmp_path):
     assert fs.telemetry.resolver_hits >= 10
 
 
-def test_resolver_disabled_restores_seed_cascade(tmp_path):
-    fs = SeaFS(make_config(tmp_path, resolver_cache=False))
-    p = os.path.join(fs.mount, "cold.bin")
-    fs.write_bytes(p, b"y" * 16)
-    with _CountingLocate(fs.hierarchy) as cl:
-        for _ in range(3):
-            assert fs.read_bytes(p) == b"y" * 16
-        # two full cascades per read, like the seed (the stripe-manifest
-        # existence probe plus the file itself)
-        assert cl.calls == 6
-    assert fs.telemetry.resolver_hits == 0
-
-
 # ------------------------------------------------------- verify-on-hit
 def test_cross_process_move_falls_back_via_verify(tmp_path):
     """Another process's flusher MOVEs the file cache→base without telling

@@ -495,3 +495,51 @@ def test_striping_disabled_is_whole_file(tmp_path):
     import glob as _glob
 
     assert not _glob.glob(str(tmp_path / "*" / "*.sea_stripe.0*"))
+
+
+# ------------------------------------------------------------- config files
+#: non-default values for the str fields (bool, int and float values are
+#: derived from the defaults); mount is required and set separately
+_STR_VALUES = {
+    "faults": "transfer.chunk:errno=EIO,p=0.5,n=3",
+    "federation_node": "node-a",
+}
+
+
+def _non_default(f):
+    if f.type == "bool":
+        return not f.default
+    if f.type == "int":
+        return f.default + 1
+    if f.type == "float":
+        return f.default + 0.25
+    return _STR_VALUES[f.name]
+
+
+@pytest.mark.parametrize("kind", ["bool", "int", "float", "str"])
+def test_from_file_reads_every_field(tmp_path, kind):
+    """Every scalar field of one declared type, set to a valid non-default
+    value in ``[sea]``, comes back from ``from_file`` as exactly that
+    value; every other field keeps its dataclass default."""
+    import dataclasses
+
+    fields = [
+        f for f in dataclasses.fields(SeaConfig) if f.type == kind and f.name != "mount"
+    ]
+    assert fields
+    if kind == "str":
+        assert {f.name for f in fields} == set(_STR_VALUES)
+    want = {f.name: _non_default(f) for f in fields}
+    lines = ["[sea]", f"mount = {tmp_path / 'mount'}"]
+    lines += [f"{k} = {v!r}" if kind == "float" else f"{k} = {v}" for k, v in want.items()]
+    lines += ["[tier.fast]", f"roots = {tmp_path / 't0'}"]
+    lines += ["[tier.pfs]", f"roots = {tmp_path / 'pfs'}", "persistent = true"]
+    ini = tmp_path / "sea.ini"
+    ini.write_text("\n".join(lines) + "\n")
+    cfg = SeaConfig.from_file(str(ini))
+    assert {name: getattr(cfg, name) for name in want} == want
+    for f in dataclasses.fields(SeaConfig):
+        if f.name not in want and f.name != "mount" and f.type in ("bool", "int", "float", "str"):
+            assert getattr(cfg, f.name) == f.default, f.name
+    assert cfg.mount == str(tmp_path / "mount")
+    assert [t.name for t in cfg.tiers] == ["fast", "pfs"] and cfg.tiers[-1].persistent

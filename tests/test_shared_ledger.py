@@ -20,7 +20,7 @@ import time
 import pytest
 
 from repro.core import Sea, SeaConfig, SeaFS, TierSpec
-from repro.core.ledger import LEDGER_DIRNAME
+from repro.core.ledger import LEDGER_DIRNAME, scan_root
 from repro.core.shared_ledger import SharedCapacityLedger, pid_alive
 from repro.core.telemetry import Telemetry, aggregate_snapshots, load_aggregate
 
@@ -298,7 +298,7 @@ def test_scans_exclude_ledger_store(tmp_path):
     try:
         sea.flusher.drain()  # settle the in-flight copy (.sea_tmp) first
         tier0 = sea.fs.hierarchy.tiers[0]
-        assert tier0.scan_used_bytes(tier0.roots[0]) == 64
+        assert sum(scan_root(tier0.roots[0]).values()) == 64
         got, want = sea.fs.hierarchy.ledger.verify(tier0.roots[0])
         assert got == want == 64
         assert sea.fs.listdir(sea.fs.mount) == ["real.bin"]
@@ -436,8 +436,6 @@ def test_config_parses_shared_ledger_flags(tmp_path):
 def test_config_rejects_bad_shared_settings(tmp_path):
     with pytest.raises(ValueError):
         make_config(str(tmp_path), leader_heartbeat_s=0.0)
-    with pytest.raises(ValueError):
-        make_config(str(tmp_path), capacity_ledger=False)  # shared needs ledger
 
 
 # ------------------------------------------------------------------- simulator

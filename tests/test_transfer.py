@@ -187,21 +187,6 @@ def test_bandwidth_throttle_paces_chunks(tmp_path):
     assert elapsed >= 0.03, elapsed
 
 
-def test_disabled_engine_keeps_atomicity_and_accounting(tmp_path):
-    """transfer_engine=False restores the seed's whole-file shutil copy
-    but must keep the atomic commit and the ledger accounting."""
-    cfg = make_config(tmp_path, transfer_engine=False, fast_capacity=1 << 20)
-    sea = Sea(cfg)
-    p = os.path.join(cfg.mount, "a.bin")
-    with sea.fs.open(p, "wb") as f:
-        f.write(b"z" * 4096)
-    dst = sea.fs.persist(p)
-    assert open(dst, "rb").read() == b"z" * 4096
-    base = sea.fs.hierarchy.base
-    assert base.used_bytes(base.roots[0]) == 4096
-    assert not tmp_files(str(tmp_path))
-
-
 # ---------------------------------------------------------- crash consistency
 @pytest.mark.parametrize("workers", [1, 4])
 def test_persist_crash_releases_reservation_no_partial(tmp_path, workers):
