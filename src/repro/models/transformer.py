@@ -461,44 +461,11 @@ class LM:
 
     @staticmethod
     def loss(p, cfg: ModelConfig, hidden, labels, mask=None, seq_chunk: int = 512):
-        """Chunked CE over the sequence: [B,S,V] never materializes."""
-        B, S, D = hidden.shape
-        table = LM.unembed_table(p, cfg)
-        nchunk = -(-S // seq_chunk)
-        pad = nchunk * seq_chunk - S
-        if pad:
-            hidden = jnp.pad(hidden, ((0, 0), (0, pad), (0, 0)))
-            labels = jnp.pad(labels, ((0, 0), (0, pad)))
-            m = jnp.pad(
-                jnp.ones((B, S), jnp.float32) if mask is None else mask,
-                ((0, 0), (0, pad)),
-            )
-        else:
-            m = jnp.ones((B, S), jnp.float32) if mask is None else mask
-
-        hs = hidden.reshape(B, nchunk, seq_chunk, D).transpose(1, 0, 2, 3)
-        ls = labels.reshape(B, nchunk, seq_chunk).transpose(1, 0, 2)
-        ms = m.reshape(B, nchunk, seq_chunk).transpose(1, 0, 2)
-
-        vmask = _vocab_pad_mask(cfg)
-
-        def chunk_body(acc, inp):
-            h, lab, mk = inp
-            logits = _scaled_logits(h, table, cfg) + vmask
-            logz = jax.nn.logsumexp(logits, axis=-1)
-            gold = jnp.take_along_axis(logits, lab[..., None], axis=-1)[..., 0]
-            nll = (logz - gold) * mk
-            return (acc[0] + jnp.sum(nll), acc[1] + jnp.sum(mk)), None
-
-        # the backward pass needs each chunk's fp32 softmax; past
-        # _LOSS_SAVE_BYTES for the whole batch, recompute it chunk by chunk
-        saved = B * nchunk * seq_chunk * cfg.vocab_padded * 4
-        body = jax.checkpoint(chunk_body) if saved > _LOSS_SAVE_BYTES else chunk_body
-        (tot, cnt), _ = jax.lax.scan(
-            body, (jnp.zeros((), jnp.float32), jnp.zeros((), jnp.float32)),
-            (hs, ls, ms),
-        )
-        return tot / jnp.maximum(cnt, 1.0)
+        """Chunked CE over the sequence: [B,S,V] never materializes. Its
+        gradient is formed in the same chunk loop (``_nll_sum``)."""
+        m = jnp.ones(labels.shape, jnp.float32) if mask is None else mask.astype(jnp.float32)
+        tot = _nll_sum(cfg, seq_chunk, hidden, LM.unembed_table(p, cfg), labels, m)
+        return tot / jnp.maximum(jnp.sum(m), 1.0)
 
     @staticmethod
     def logits(p, cfg: ModelConfig, hidden):
@@ -611,12 +578,91 @@ class LM:
         return logits, new_caches
 
 
+# ------------------------------------------------------------------ loss
+def _nll_chunks(cfg: ModelConfig, seq_chunk: int, hidden, table, labels, mask,
+                with_grad: bool):
+    """Masked nll summed over the sequence, one ``seq_chunk`` at a time.
+
+    With ``with_grad`` each chunk also turns its logits into the gradient
+    while they are live: dlogits = (softmax - onehot) * mask, then
+    dh = dlogits . table and dtable += dlogits^T . h. That is three vocab
+    products a chunk, and neither the fp32 logits nor a recompute of them
+    is left for the backward pass. Returns (nll sum, dh, dtable), the last
+    two None without ``with_grad``.
+    """
+    B, S, D = hidden.shape
+    nchunk = -(-S // seq_chunk)
+    pad = nchunk * seq_chunk - S
+    if pad:
+        hidden = jnp.pad(hidden, ((0, 0), (0, pad), (0, 0)))
+        labels = jnp.pad(labels, ((0, 0), (0, pad)))
+        mask = jnp.pad(mask, ((0, 0), (0, pad)))
+
+    def chunks(x):
+        return x.reshape(B, nchunk, seq_chunk, *x.shape[2:]).swapaxes(0, 1)
+
+    vmask = _vocab_pad_mask(cfg)
+    # 1/logits_scaling: d(logits)/d(table . h)
+    scale = 1.0 if cfg.logits_scaling is None else 1.0 / cfg.logits_scaling
+    # the operand dtype of the two gradient products: bf16 for a bf16 model
+    op_dtype = jnp.result_type(hidden.dtype, table.dtype)
+    head = table.shape[0] == D  # an untied [D, V] head, as unembed_logits reads it
+
+    def body(carry, inp):
+        tot, dtable = carry
+        h, lab, mk = inp
+        logits = _scaled_logits(h, table, cfg) + vmask
+        logz = jax.nn.logsumexp(logits, axis=-1)
+        gold = jnp.take_along_axis(logits, lab[..., None], axis=-1)[..., 0]
+        tot = tot + jnp.sum((logz - gold) * mk)
+        if not with_grad:
+            return (tot, dtable), None
+        probs = jnp.exp(logits - logz[..., None])
+        onehot = jax.nn.one_hot(lab, logits.shape[-1], dtype=jnp.float32)
+        dlogits = ((probs - onehot) * (mk * scale)[..., None]).astype(op_dtype)
+        f32 = jnp.float32
+        if head:
+            dh = jnp.einsum("bsv,dv->bsd", dlogits, table, preferred_element_type=f32)
+            dt = jnp.einsum("bsd,bsv->dv", h, dlogits, preferred_element_type=f32)
+        else:
+            dh = jnp.einsum("bsv,vd->bsd", dlogits, table, preferred_element_type=f32)
+            dt = jnp.einsum("bsv,bsd->vd", dlogits, h, preferred_element_type=f32)
+        dtable = (dtable.astype(f32) + dt).astype(table.dtype)
+        return (tot, dtable), dh.astype(hidden.dtype)
+
+    dtable0 = jnp.zeros(table.shape, table.dtype) if with_grad else None
+    (tot, dtable), dh = jax.lax.scan(
+        body, (jnp.zeros((), jnp.float32), dtable0),
+        (chunks(hidden), chunks(labels), chunks(mask)),
+    )
+    if with_grad:
+        dh = dh.swapaxes(0, 1).reshape(B, nchunk * seq_chunk, D)[:, :S]
+    return tot, dh, dtable
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1))
+def _nll_sum(cfg: ModelConfig, seq_chunk: int, hidden, table, labels, mask):
+    """Masked nll of the unembedded ``hidden`` summed over every token."""
+    return _nll_chunks(cfg, seq_chunk, hidden, table, labels, mask, False)[0]
+
+
+def _nll_sum_fwd(cfg, seq_chunk, hidden, table, labels, mask):
+    with jax.named_scope("loss.fwd_grad"):
+        tot, dh, dtable = _nll_chunks(cfg, seq_chunk, hidden, table, labels, mask, True)
+    return tot, (dh, dtable)
+
+
+def _nll_sum_bwd(cfg, seq_chunk, res, g):
+    dh, dtable = res
+    with jax.named_scope("loss.bwd_scale"):
+        return ((g * dh).astype(dh.dtype), (g * dtable).astype(dtable.dtype),
+                None, None)
+
+
+_nll_sum.defvjp(_nll_sum_fwd, _nll_sum_bwd)
+
+
 # ------------------------------------------------------------------ misc
-# bytes of fp32 softmax that LM.loss keeps for the backward pass before it
-# recomputes each chunk's logits instead
-_LOSS_SAVE_BYTES = 2 << 30
-
-
 def _scaled_logits(h, table, cfg: ModelConfig):
     """fp32 logits, divided by the config's logits scaling if set."""
     logits = unembed_logits(h, table)

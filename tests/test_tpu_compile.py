@@ -166,3 +166,66 @@ def test_mamba2_grad_runs_ssd_kernel_for_v5e(one_chip):
     assert re.search(r"ssd_intra_fwd[.\w]* = ", hlo) and re.search(r"ssd_intra_bwd[.\w]* = ", hlo)
     assert not re.search(r"f32\[[0-9,]*256,256\]", hlo)
     assert _hbm_bytes(compiled) <= V5E_HBM_BYTES
+
+
+def _vocab_convolutions_in_loops(hlo: str, vocab: int) -> int:
+    """Convolutions reachable from a ``while`` body, fusions included, with
+    an operand or result that has a dimension of ``vocab``."""
+    comps: dict[str, list[str]] = {}
+    shapes: dict[str, str] = {}
+    lines: list[str] = []
+    for line in hlo.splitlines():
+        head = re.match(r"(?:ENTRY )?%?([\w.\-]+) .*\{$", line)
+        if head and not line.startswith(" "):
+            lines = comps.setdefault(head.group(1), [])
+            continue
+        inst = re.match(r"\s*(?:ROOT )?%([\w.\-]+) = (\S+)", line)
+        if inst:
+            lines.append(line)
+            shapes[inst.group(1)] = inst.group(2)
+
+    def has_vocab(shape: str) -> bool:
+        return any(str(vocab) in re.split(r"[,\[\]]", dims)
+                   for dims in re.findall(r"\[([\d,]*)\]", shape))
+
+    seen: set[str] = set()
+
+    def count(comp: str) -> int:
+        n = 0
+        for line in comps.get(comp, []):
+            conv = re.search(r"= (\S+) convolution\(([^)]*)\)", line)
+            if conv:
+                operands = re.findall(r"%([\w.\-]+)", conv.group(2))
+                n += any(has_vocab(s) for s in (conv.group(1), *map(shapes.get, operands)))
+            for sub in re.findall(r"(?:calls|to_apply|body|condition)=%([\w.\-]+)", line):
+                if sub not in seen:
+                    seen.add(sub)
+                    n += count(sub)
+        return n
+
+    bodies = [b for line in hlo.splitlines() for b in re.findall(r" while\(.*body=%([\w.\-]+)", line)]
+    return sum(count(b) for b in bodies if b not in seen)
+
+
+def test_loss_grad_runs_three_vocab_products_a_chunk_for_v5e(one_chip):
+    """The gradient of ``LM.loss`` compiled for a v5e: its chunk loop holds
+    three products with the vocabulary (the logits, dh and dtable), so XLA
+    did not compute the logits a second time inside the loop."""
+    from repro.configs.base import ModelConfig
+    from repro.models.transformer import LM
+
+    D, V = 256, 8000
+    cfg = ModelConfig(name="loss-v5e", family="dense", n_layers=1, d_model=D, d_ff=512,
+                      vocab_size=V, tie_embeddings=True, logits_scaling=8.0)
+    p = {"embed": _on(one_chip, (cfg.vocab_padded, D), jnp.bfloat16)}
+    hidden = _on(one_chip, (2, 2048, D), jnp.bfloat16)
+    labels = _on(one_chip, (2, 2048), jnp.int32)
+
+    def loss(p, h, labels):
+        return LM.loss(p, cfg, h, labels, seq_chunk=512)
+
+    # the value too, as the train step returns it: else XLA drops the product
+    # that only the value needs
+    grad = jax.value_and_grad(loss, argnums=(0, 1))
+    compiled = jax.jit(grad).lower(p, hidden, labels).compile()
+    assert _vocab_convolutions_in_loops(compiled.as_text(), cfg.vocab_padded) == 3
